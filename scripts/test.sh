@@ -15,10 +15,11 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 
-# Known-red ledger.  Every entry is a test we KNOW fails and have chosen
-# to ship anyway; since the grad-accum fix (PR 2) the list is empty, and
-# this gate keeps it that way: adding an entry fails the suite loudly
-# instead of quietly normalizing red.
+# Known-red ledger.  Every entry would be a test we KNOW fails and have
+# chosen to ship anyway.  The list stays empty by rule — adding an entry
+# fails the suite loudly instead of quietly normalizing red — which is
+# not a claim that the suite is green: failing tests are reported in
+# CHANGES.md until they are fixed.
 KNOWN_RED=()
 if [ "${#KNOWN_RED[@]}" -ne 0 ]; then
     echo "FATAL: known-red list must stay empty; fix or delete the tests" >&2

@@ -74,9 +74,10 @@ class ModelConfig:
     #: for the whole layer stack (layer axis in the kernel grid; jamba
     #: attention sublayers excepted), "fused" = single launch per layer
     #: for the state-update/contraction/gate chain, "xla" = the ref.py
-    #: oracle, "auto" = megakernel on TPU, else fused where it compiles
-    #: natively (everywhere for pure-XLA fused steps); the
-    #: REPRO_STEP_IMPL env var overrides "auto" only
+    #: oracle, "auto" = on TPU the megakernel where one layer's blocks
+    #: fit the kernel VMEM budget, else fused; off TPU fused where it
+    #: is pure XLA, else xla (core.selective_scan.resolve_step_impl);
+    #: the REPRO_STEP_IMPL env var overrides "auto" only
     step_impl: str = "auto"          # auto | megakernel | fused | xla
     attn_impl: str = "chunked"       # chunked | ref | pallas
     attn_chunk: int = 512

@@ -25,7 +25,8 @@ import os
 import jax
 import jax.numpy as jnp
 
-from repro.core import approx
+from repro.core import approx, state_quant
+from repro.kernels import backend
 from repro.kernels import ref as kref
 
 selective_scan_seq = kref.selective_scan
@@ -146,23 +147,32 @@ def get_scan(name: str):
 # Single-token decode step (the serving engine's per-layer hot path)
 # ---------------------------------------------------------------------------
 
-def resolve_step_impl(name: str, needs_pallas: bool = True) -> str:
+def resolve_step_impl(name: str, needs_pallas: bool = True,
+                      megakernel_vmem: int | None = None) -> str:
     """Resolve cfg.step_impl to a concrete impl.
 
-    "auto" picks the cross-layer megakernel where Pallas compiles
-    natively (TPU) and otherwise the per-layer fused kernel / XLA
-    reference split that served before: fused where it is pure XLA
-    (``needs_pallas=False``, e.g. xLSTM's chained paths), the XLA
-    reference elsewhere.  The ``REPRO_STEP_IMPL`` env var overrides
-    "auto" only — explicit config always wins — so CI can sweep the
-    whole suite over an impl without touching configs.  Callers can
-    force any impl with "megakernel" / "fused" / "xla" (parity tests
-    and TPU-less benchmarking do)."""
+    "auto" on a TPU picks the cross-layer megakernel when one grid
+    step's VMEM need — ``megakernel_vmem``, which the caller computes
+    from the operands it would launch with (weights and pooled state of
+    one layer at the served widths and slot count,
+    kernels.decode_step.stacked_layer_vmem_bytes) — fits the chip's
+    kernel VMEM budget, and the per-layer fused kernel otherwise (or
+    when the caller gives no need: a family without a megakernel
+    estimate).  Off TPU it keeps the split that served before: fused
+    where it is pure XLA (``needs_pallas=False``, e.g. xLSTM's chained
+    paths), the XLA reference elsewhere.  Both inputs are observed, not
+    configured.  The ``REPRO_STEP_IMPL`` env var overrides "auto" only
+    — explicit config always wins — so CI can sweep the whole suite
+    over an impl without touching configs.  Callers can force any impl
+    with "megakernel" / "fused" / "xla" (parity tests and TPU-less
+    benchmarking do)."""
     if name == "auto":
         name = os.environ.get("REPRO_STEP_IMPL", "auto")
     if name == "auto":
-        if jax.default_backend() == "tpu":
-            return "megakernel"
+        if backend.on_tpu():
+            fits = (megakernel_vmem is not None
+                    and megakernel_vmem <= backend.vmem_budget_bytes())
+            return "megakernel" if fits else "fused"
         if not needs_pallas:
             return "fused"
         return "xla"
@@ -187,6 +197,47 @@ def resolve_cell_impl(name: str, needs_pallas: bool = True) -> str:
     return "fused" if r == "megakernel" else r
 
 
+def _per_channel_shard(fn, args, axes, out_axes, group: int = 1):
+    """Call ``fn(*args)`` — a fused Pallas step — once per channel shard
+    of the active mesh.
+
+    A Mosaic kernel cannot be partitioned automatically, and the decode
+    step is independent across d_inner channels, so under a mesh each
+    device runs the kernel on the channels the "act_ffn" rule gives it
+    (where the pooled state already lives).  ``axes[i]`` is args[i]'s
+    channel axis (None: replicated; a None arg passes through);
+    ``out_axes`` the outputs' (axis, ndim).  A shard must hold a whole
+    number of ``group`` channels (quantized state keeps one scale per
+    D_BLOCK channels); otherwise every device runs the whole step.
+    Without a mesh this is ``fn(*args)``."""
+    from jax.sharding import PartitionSpec as P
+    from repro.parallel import sharding
+    mesh = sharding.active_mesh()
+    if mesh is None:
+        return fn(*args)
+    name = sharding.logical_to_spec(("act_ffn",))[0]
+    d = args[0].shape[1]
+    if name is not None and d % (mesh.shape[name] * group):
+        name = None
+
+    def spec(ax, ndim):
+        return P(*(name if i == ax else None for i in range(ndim)))
+
+    live = [i for i, a in enumerate(args) if a is not None]
+
+    def body(*xs):
+        full = list(args)
+        for i, x in zip(live, xs):
+            full[i] = x
+        return fn(*full)
+
+    return jax.shard_map(
+        body, mesh=mesh,
+        in_specs=tuple(spec(axes[i], args[i].ndim) for i in live),
+        out_specs=tuple(spec(ax, nd) for ax, nd in out_axes),
+        check_vma=False)(*(args[i] for i in live))
+
+
 def decode_step(h, x_t, dt_t, A, B_t, C_t, D=None, z_t=None,
                 impl: str = "xla",
                 exp_impl: str = "exact", silu_impl: str = "exact",
@@ -208,9 +259,14 @@ def decode_step(h, x_t, dt_t, A, B_t, C_t, D=None, z_t=None,
         a_scale = None
     if impl in ("fused", "pallas"):
         from repro.kernels import decode_step as dsk   # lazy: import cycle
-        return dsk.selective_state_step(
-            h, x_t, dt_t, A, B_t, C_t, D=D, z_t=z_t,
-            exp_impl=exp_impl, silu_impl=silu_impl, a_scale=a_scale)
+
+        def fused(h, x_t, dt_t, A, B_t, C_t, D, z_t, a_scale):
+            return dsk.selective_state_step(
+                h, x_t, dt_t, A, B_t, C_t, D=D, z_t=z_t,
+                exp_impl=exp_impl, silu_impl=silu_impl, a_scale=a_scale)
+        return _per_channel_shard(
+            fused, (h, x_t, dt_t, A, B_t, C_t, D, z_t, a_scale),
+            (1, 1, 1, 0, None, None, 0, 1, 0), ((1, 2), (1, 3)))
     if impl != "xla":
         # "auto" must go through resolve_step_impl first; a typo or raw
         # cfg string silently falling back to the unfused path would eat
@@ -240,10 +296,16 @@ def decode_step_q(hq, h_scale, x_t, dt_t, A, B_t, C_t, D=None, z_t=None,
         a_scale = None
     if impl in ("fused", "pallas"):
         from repro.kernels import decode_step as dsk   # lazy: import cycle
-        return dsk.selective_state_step_q(
-            hq, h_scale, x_t, dt_t, A, B_t, C_t, D=D, z_t=z_t,
-            state_dtype=state_dtype, exp_impl=exp_impl,
-            silu_impl=silu_impl, a_scale=a_scale)
+
+        def fused(hq, h_scale, x_t, dt_t, A, B_t, C_t, D, z_t, a_scale):
+            return dsk.selective_state_step_q(
+                hq, h_scale, x_t, dt_t, A, B_t, C_t, D=D, z_t=z_t,
+                state_dtype=state_dtype, exp_impl=exp_impl,
+                silu_impl=silu_impl, a_scale=a_scale)
+        return _per_channel_shard(
+            fused, (hq, h_scale, x_t, dt_t, A, B_t, C_t, D, z_t, a_scale),
+            (1, 1, 1, 1, 0, None, None, 0, 1, 0),
+            ((1, 2), (1, 3), (1, 2)), group=state_quant.D_BLOCK)
     if impl != "xla":
         raise KeyError(f"unknown step impl {impl!r}")
     return kref.selective_state_step_q(

@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import math
 
+import jax
 import jax.numpy as jnp
 
 #: storage dtypes accepted by cfg.state_dtype
@@ -108,36 +109,38 @@ def update_scale(amax, prev_scale, state_dtype: str):
 # SSM h: (..., d, n) payload, (..., g) scales (g = n_groups(d))
 # ---------------------------------------------------------------------------
 
-def _group_h(x):
-    """(..., d, n) -> (..., g, blk, n) with zero padding; blk = group."""
-    *lead, d, n = x.shape
-    g = n_groups(d)
-    blk = min(D_BLOCK, d) if g == 1 else D_BLOCK
-    pad = g * blk - d
-    if pad:
-        x = jnp.pad(x, [(0, 0)] * len(lead) + [(0, pad), (0, 0)])
-    return x.reshape(*lead, g, blk, n), d
+def _group_parts(x, axis: int, size: int = D_BLOCK):
+    """Static ``size``-wide slices of ``x`` along ``axis``: one per
+    scale group (channels, or size 1 on the group axis of scales)."""
+    d = x.shape[axis]
+    return [jax.lax.slice_in_dim(x, i, min(i + size, d), axis=axis)
+            for i in range(0, d, size)]
 
 
 def quantize_h(h, state_dtype: str, prev_scale=None):
     """Quantize an SSM state (..., d, n) -> (payload, scale (..., g)).
 
     ``prev_scale`` feeds the decayed-running-absmax update; None means
-    cold start (prefill of a fresh slot) and uses the step's absmax."""
-    grouped, d = _group_h(h.astype(jnp.float32))
-    amax = jnp.max(jnp.abs(grouped), axis=(-2, -1))         # (..., g)
+    cold start (prefill of a fresh slot) and uses the step's absmax.
+    Groups are static channel slices, never a reshape of the channel
+    axis, which is what lets the megakernel body lower this on TPU."""
+    parts = _group_parts(h.astype(jnp.float32), -2)
+    amax = jnp.concatenate(
+        [jnp.max(jnp.max(jnp.abs(x), axis=-1), axis=-1, keepdims=True)
+         for x in parts], axis=-1)                           # (..., g)
     scale = update_scale(amax, prev_scale, state_dtype)
-    codes = encode(grouped / scale[..., None, None], state_dtype)
-    *lead, g, blk, n = codes.shape
-    return codes.reshape(*lead, g * blk, n)[..., :d, :], scale
+    codes = jnp.concatenate(
+        [encode(x / s[..., None], state_dtype)
+         for x, s in zip(parts, _group_parts(scale, -1, 1))], axis=-2)
+    return codes, scale
 
 
 def dequantize_h(q, scale):
     """Inverse of quantize_h (up to rounding): (..., d, n) f32."""
-    grouped, d = _group_h(q.astype(jnp.float32))
-    out = grouped * scale[..., None, None]
-    *lead, g, blk, n = out.shape
-    return out.reshape(*lead, g * blk, n)[..., :d, :]
+    return jnp.concatenate(
+        [x.astype(jnp.float32) * s[..., None]
+         for x, s in zip(_group_parts(q, -2), _group_parts(scale, -1, 1))],
+        axis=-2)
 
 
 # ---------------------------------------------------------------------------

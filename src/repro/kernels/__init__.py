@@ -1,6 +1,7 @@
 """Pallas TPU kernels for MARCA hot spots + pure-jnp oracles.
 
-Kernels (each validated against ``ref.py`` with interpret=True on CPU):
+Kernels (each validated against ``ref.py`` on CPU, where they run under
+the Pallas interpreter; compiled on TPU — see ``backend.py``):
 
   * ``selective_scan`` — fused selective-SSM scan (the paper's core).
   * ``fast_exp``       — biased Schraudolph exponential (EXP-RCU).

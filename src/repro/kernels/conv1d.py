@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels import pallas_compat
+from repro.kernels import backend
 
 
 def _conv_kernel(x_ref, w_ref, b_ref, xprev_ref, y_ref, tail_ref, hist,
@@ -42,7 +42,7 @@ def _conv_kernel(x_ref, w_ref, b_ref, xprev_ref, y_ref, tail_ref, hist,
 @functools.partial(jax.jit, static_argnames=("block_d", "block_l",
                                              "interpret"))
 def _conv_padded(x, w, b, x_prev, block_d: int, block_l: int,
-                 interpret: bool):
+                 interpret: bool | None):
     bsz, L, d = x.shape
     k = w.shape[0]
     has_bias = b is not None
@@ -77,15 +77,15 @@ def _conv_padded(x, w, b, x_prev, block_d: int, block_l: int,
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[pltpu.VMEM((k - 1, block_d), jnp.float32)],
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=backend.resolve_interpret(interpret),
         name="marca_causal_conv1d",
     )(*args)
 
 
 def causal_conv1d(x, w, b=None, x_prev=None, block_d: int = 256,
-                  block_l: int = 256, interpret: bool = True):
+                  block_l: int = 256, interpret: bool | None = None):
     """x (b, L, d); w (k, d); b (d,)|None; x_prev (b, k-1, d)|None.
 
     Returns (y (b, L, d), new_state (b, k-1, d)) matching

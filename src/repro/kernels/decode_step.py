@@ -18,12 +18,12 @@ kernel over the slot-pooled state: state in, token out, one launch.
 
 Layout mirrors the scan kernel: channels D on lanes (128-aligned),
 state N on sublanes; h is carried as (slots, N, D).  Grid is
-(slots, D-blocks), both parallel — a decode step has no sequential
-axis, which is exactly why it fuses so cleanly.
+(slot-blocks, D-blocks), both parallel — a decode step has no
+sequential axis, which is exactly why it fuses so cleanly.
 
-``interpret=True`` (the default) is the CPU fallback: the same kernel
-body runs under the Pallas interpreter, so every CPU test exercises
-the fused path; on real TPU callers pass interpret=False.
+``interpret=None`` (the default) compiles on TPU and runs the same
+kernel body under the Pallas interpreter elsewhere (kernels.backend),
+so every CPU test exercises the fused path.
 """
 from __future__ import annotations
 
@@ -34,9 +34,10 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import approx, state_quant
-from repro.kernels import pallas_compat
+from repro.kernels import backend
 
 
 # ---------------------------------------------------------------------------
@@ -199,211 +200,163 @@ def slstm_cell() -> CellSkeleton:
 def _chain(h, x_ref, dt_ref, at_ref, at_scale_ref, b_ref, c_ref, d_ref,
            z_ref, *, exp_impl: str, silu_impl: str, has_d: bool,
            has_z: bool, wq: bool):
-    """The fused per-token chain on one (slot, D-block) grid cell:
+    """The fused per-token chain on one (slot-block, D-block) grid cell:
     block loads + f32 casts around the S6 cell skeleton.
-    h (N, BD) f32 already dequantized; with ``wq`` the At block holds
+    h (BB, N, BD) f32 already dequantized; with ``wq`` the At block holds
     int8 codes and at_scale_ref the (1, BD) per-channel scales the
     cell's dequant phase expands them with.
-    Returns (y (BD,), h_new (N, BD))."""
+    Returns (y (BB, BD), h_new (BB, N, BD))."""
     cell = s6_cell(exp_impl, silu_impl, has_d, has_z, wq)
     ins = {
-        "x": x_ref[0, :].astype(jnp.float32),          # (BD,)
-        "dt": dt_ref[0, :].astype(jnp.float32),        # (BD,)
+        "x": x_ref[...].astype(jnp.float32),           # (BB, BD)
+        "dt": dt_ref[...].astype(jnp.float32),         # (BB, BD)
         "at": at_ref[...].astype(jnp.float32),         # (N, BD)
-        "b": b_ref[0, :].astype(jnp.float32),          # (N,)
-        "c": c_ref[0, :].astype(jnp.float32),          # (N,)
+        "b": b_ref[...].astype(jnp.float32),           # (BB, N)
+        "c": c_ref[...].astype(jnp.float32),           # (BB, N)
         "d": d_ref[0, :].astype(jnp.float32) if has_d else None,
-        "z": z_ref[0, :].astype(jnp.float32) if has_z else None,
+        "z": z_ref[...].astype(jnp.float32) if has_z else None,
     }
     if wq:
         ins["at_scale"] = at_scale_ref[0, :].astype(jnp.float32)  # (BD,)
     return cell(h, ins)
 
 
-def _step_kernel(h_ref, x_ref, dt_ref, at_ref, at_scale_ref, b_ref, c_ref,
-                 d_ref, z_ref, y_ref, hout_ref, *, exp_impl: str,
-                 silu_impl: str, has_d: bool, has_z: bool, wq: bool):
-    h = h_ref[0].astype(jnp.float32)               # (N, BD)
-    y, h_new = _chain(h, x_ref, dt_ref, at_ref, at_scale_ref, b_ref, c_ref,
-                      d_ref, z_ref, exp_impl=exp_impl, silu_impl=silu_impl,
+def _step_kernel(*refs, exp_impl: str, silu_impl: str, has_d: bool,
+                 has_z: bool, wq: bool, state_dtype: str):
+    """One grid cell of the decode step.  With a quantized
+    ``state_dtype`` the int8/fp8 payload is dequantized on read and
+    requantized on write *inside* the kernel, so the f32 state lives
+    only in VMEM/registers — never in HBM.  Each grid cell owns one
+    channel group's scale per slot (scale blocking == channel
+    blocking), so the running-absmax update needs no cross-block
+    reduction."""
+    quant = state_quant.is_quantized(state_dtype)
+    if quant:
+        h_ref, scale_ref, *in_refs, y_ref, hout_ref, scale_out_ref = refs
+    else:
+        h_ref, *in_refs, y_ref, hout_ref = refs
+    h = h_ref[...].astype(jnp.float32)             # (BB, N, BD)
+    if quant:
+        s_in = scale_ref[0]                        # (BB, 1, 1)
+        h = h * s_in                               # dequant on read
+    y, h_new = _chain(h, *in_refs, exp_impl=exp_impl, silu_impl=silu_impl,
                       has_d=has_d, has_z=has_z, wq=wq)
-    y_ref[0, :] = y.astype(y_ref.dtype)
-    hout_ref[0] = h_new.astype(hout_ref.dtype)
-
-
-def _step_kernel_q(h_ref, scale_ref, x_ref, dt_ref, at_ref, at_scale_ref,
-                   b_ref, c_ref, d_ref, z_ref, y_ref, hout_ref,
-                   scale_out_ref, *, exp_impl: str, silu_impl: str,
-                   has_d: bool, has_z: bool, state_dtype: str, wq: bool):
-    """Quantized-state variant: the int8/fp8 payload is dequantized on
-    read and requantized on write *inside* the kernel, so the f32 state
-    lives only in VMEM/registers — never in HBM.  Each grid cell owns
-    one channel group's scale (scale blocking == channel blocking), so
-    the running-absmax update needs no cross-block reduction."""
-    s_in = scale_ref[0, 0]
-    h = h_ref[0].astype(jnp.float32) * s_in        # dequant on read
-    y, h_new = _chain(h, x_ref, dt_ref, at_ref, at_scale_ref, b_ref, c_ref,
-                      d_ref, z_ref, exp_impl=exp_impl, silu_impl=silu_impl,
-                      has_d=has_d, has_z=has_z, wq=wq)
-    y_ref[0, :] = y.astype(y_ref.dtype)
-    amax = jnp.max(jnp.abs(h_new))
+    y_ref[...] = y.astype(y_ref.dtype)
+    if not quant:
+        hout_ref[...] = h_new.astype(hout_ref.dtype)
+        return
+    amax = jnp.max(jnp.max(jnp.abs(h_new), axis=-1, keepdims=True),
+                   axis=-2, keepdims=True)         # (BB, 1, 1)
     s_out = state_quant.update_scale(amax, s_in, state_dtype)
-    hout_ref[0] = state_quant.encode(h_new / s_out, state_dtype)
-    scale_out_ref[0, 0] = s_out
+    hout_ref[...] = state_quant.encode(h_new / s_out, state_dtype)
+    scale_out_ref[0] = s_out
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("block_d", "exp_impl", "silu_impl", "interpret"))
-def _step_padded(h, x_t, dt_t, at, at_scale, b_t, c_t, d_skip, z_t,
-                 block_d: int, exp_impl: str, silu_impl: str,
-                 interpret: bool):
-    """All channel-dim inputs pre-padded: D % block_d == 0.  ``at_scale``
-    (1, D) rides the same d_skip-style per-channel blocking; None means
-    f32 weights (placeholder block, dequant phase compiled out)."""
+    static_argnames=("block_b", "block_d", "exp_impl", "silu_impl",
+                     "state_dtype", "interpret"))
+def _step_padded(h, h_scale, x_t, dt_t, at, at_scale, b_t, c_t, d_skip,
+                 z_t, *, block_b: int, block_d: int, exp_impl: str,
+                 silu_impl: str, state_dtype: str, interpret: bool):
+    """One launch over pre-padded inputs: slots % block_b == 0 and
+    D % block_d == 0.  h (slots, N, D) in the storage dtype; h_scale
+    (D // block_d, slots, 1, 1) f32 for a quantized ``state_dtype``,
+    else None.  ``at_scale`` (1, D) rides the d_skip-style per-channel
+    blocking; None means f32 weights (placeholder block, dequant phase
+    compiled out).
+
+    Every block is legal for the TPU's (8, 128) tiling at any slot
+    count: a slot block is 8 rows or the whole pool, channel blocks are
+    multiples of 128, and N-wide or unit dims are whole array dims."""
     bsz, n, d_in = h.shape
-    has_d = d_skip is not None
-    has_z = z_t is not None
-    wq = at_scale is not None
-    grid = (bsz, d_in // block_d)
+    quant = h_scale is not None
+    grid = (bsz // block_b, d_in // block_d)
+    state = pl.BlockSpec((block_b, n, block_d), lambda bb, dd: (bb, 0, dd))
+    scale = pl.BlockSpec((1, block_b, 1, 1), lambda bb, dd: (dd, bb, 0, 0))
+    row = pl.BlockSpec((block_b, block_d), lambda bb, dd: (bb, dd))
+    vec = pl.BlockSpec((block_b, n), lambda bb, dd: (bb, 0))
+    chan = pl.BlockSpec((1, block_d), lambda bb, dd: (0, dd))
+    unused = pl.BlockSpec((1, 1), lambda bb, dd: (0, 0))
 
-    def _row(_):
-        return pl.BlockSpec((1, block_d), lambda bb, dd: (bb, dd))
+    def _opt(arg, spec):
+        if arg is None:
+            return jnp.zeros((1, 1), jnp.float32), unused
+        return arg, spec
 
-    in_specs = [
-        pl.BlockSpec((1, n, block_d), lambda bb, dd: (bb, 0, dd)),   # h
-        _row("x"), _row("dt"),
-        pl.BlockSpec((n, block_d), lambda bb, dd: (0, dd)),          # At
-    ]
-    args = [h, x_t, dt_t, at]
-    if wq:
-        in_specs.append(pl.BlockSpec((1, block_d), lambda bb, dd: (0, dd)))
-        args.append(at_scale)
-    else:
-        in_specs.append(pl.BlockSpec((1, 1), lambda bb, dd: (0, 0)))
-        args.append(jnp.zeros((1, 1), jnp.float32))
-    in_specs += [
-        pl.BlockSpec((1, n), lambda bb, dd: (bb, 0)),                # B_t
-        pl.BlockSpec((1, n), lambda bb, dd: (bb, 0)),                # C_t
-    ]
-    args += [b_t, c_t]
-    if has_d:
-        in_specs.append(pl.BlockSpec((1, block_d), lambda bb, dd: (0, dd)))
-        args.append(d_skip)
-    else:
-        in_specs.append(pl.BlockSpec((1, 1), lambda bb, dd: (0, 0)))
-        args.append(jnp.zeros((1, 1), jnp.float32))
-    if has_z:
-        in_specs.append(_row("z"))
-        args.append(z_t)
-    else:
-        in_specs.append(pl.BlockSpec((1, 1), lambda bb, dd: (0, 0)))
-        args.append(jnp.zeros((1, 1), jnp.float32))
+    pairs = ([(h, state)] + ([(h_scale, scale)] if quant else [])
+             + [(x_t, row), (dt_t, row),
+                (at, pl.BlockSpec((n, block_d), lambda bb, dd: (0, dd))),
+                _opt(at_scale, chan), (b_t, vec), (c_t, vec),
+                _opt(d_skip, chan), _opt(z_t, row)])
+    args, in_specs = zip(*pairs)
 
-    out_shapes = (
-        jax.ShapeDtypeStruct((bsz, d_in), x_t.dtype),
-        jax.ShapeDtypeStruct((bsz, n, d_in), jnp.float32),
-    )
-    out_specs = (
-        pl.BlockSpec((1, block_d), lambda bb, dd: (bb, dd)),
-        pl.BlockSpec((1, n, block_d), lambda bb, dd: (bb, 0, dd)),
-    )
+    out_shapes = [jax.ShapeDtypeStruct((bsz, d_in), x_t.dtype),
+                  jax.ShapeDtypeStruct(h.shape, h.dtype)]
+    out_specs = [row, state]
+    if quant:
+        out_shapes.append(jax.ShapeDtypeStruct(h_scale.shape, jnp.float32))
+        out_specs.append(scale)
 
     kernel = functools.partial(
         _step_kernel, exp_impl=exp_impl, silu_impl=silu_impl,
-        has_d=has_d, has_z=has_z, wq=wq)
+        has_d=d_skip is not None, has_z=z_t is not None,
+        wq=at_scale is not None, state_dtype=state_dtype)
 
     return pl.pallas_call(
         kernel,
-        out_shape=out_shapes,
+        out_shape=tuple(out_shapes),
         grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        compiler_params=pallas_compat.CompilerParams(
+        in_specs=list(in_specs),
+        out_specs=tuple(out_specs),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-        name="marca_decode_step",
+        name="marca_decode_step_q" if quant else "marca_decode_step",
     )(*args)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("block_d", "exp_impl", "silu_impl", "state_dtype",
-                     "interpret"))
-def _step_padded_q(h, h_scale, x_t, dt_t, at, at_scale, b_t, c_t, d_skip,
-                   z_t, block_d: int, exp_impl: str, silu_impl: str,
-                   state_dtype: str, interpret: bool):
-    """Quantized-state launch: D % block_d == 0 and the scale array has
-    exactly one entry per (slot, D-block).  ``at_scale`` as in
-    ``_step_padded`` — W8A8 composes with the quantized state."""
-    bsz, n, d_in = h.shape
-    has_d = d_skip is not None
-    has_z = z_t is not None
-    wq = at_scale is not None
-    g = d_in // block_d
-    grid = (bsz, g)
+def _launch_step(h, h_scale, x_t, dt_t, A, B_t, C_t, D, z_t, a_scale, *,
+                 block_d: int, exp_impl: str, silu_impl: str,
+                 state_dtype: str, interpret: bool | None):
+    """Pad slots and channels to the kernel blocks, launch, unpad.
 
-    def _row(_):
-        return pl.BlockSpec((1, block_d), lambda bb, dd: (bb, dd))
+    h (b, d, n) storage payload; h_scale (b, g) f32 or None.  A pool of
+    at most SUBLANES slots is one slot block; a larger pool is padded
+    to a multiple of SUBLANES and blocked by it."""
+    bsz, d_in, n = h.shape
+    block_b = bsz if bsz <= backend.SUBLANES else backend.SUBLANES
+    pad_b = (-bsz) % block_b
+    pad_d = (-d_in) % block_d
 
-    in_specs = [
-        pl.BlockSpec((1, n, block_d), lambda bb, dd: (bb, 0, dd)),   # h
-        pl.BlockSpec((1, 1), lambda bb, dd: (bb, dd)),               # scale
-        _row("x"), _row("dt"),
-        pl.BlockSpec((n, block_d), lambda bb, dd: (0, dd)),          # At
-    ]
-    args = [h, h_scale, x_t, dt_t, at]
-    if wq:
-        in_specs.append(pl.BlockSpec((1, block_d), lambda bb, dd: (0, dd)))
-        args.append(at_scale)
-    else:
-        in_specs.append(pl.BlockSpec((1, 1), lambda bb, dd: (0, 0)))
-        args.append(jnp.zeros((1, 1), jnp.float32))
-    in_specs += [
-        pl.BlockSpec((1, n), lambda bb, dd: (bb, 0)),                # B_t
-        pl.BlockSpec((1, n), lambda bb, dd: (bb, 0)),                # C_t
-    ]
-    args += [b_t, c_t]
-    if has_d:
-        in_specs.append(pl.BlockSpec((1, block_d), lambda bb, dd: (0, dd)))
-        args.append(d_skip)
-    else:
-        in_specs.append(pl.BlockSpec((1, 1), lambda bb, dd: (0, 0)))
-        args.append(jnp.zeros((1, 1), jnp.float32))
-    if has_z:
-        in_specs.append(_row("z"))
-        args.append(z_t)
-    else:
-        in_specs.append(pl.BlockSpec((1, 1), lambda bb, dd: (0, 0)))
-        args.append(jnp.zeros((1, 1), jnp.float32))
+    def _pad_row(t):
+        if t is None:
+            return None
+        return jnp.pad(t, ((0, pad_b), (0, pad_d)))
 
-    out_shapes = (
-        jax.ShapeDtypeStruct((bsz, d_in), x_t.dtype),
-        jax.ShapeDtypeStruct((bsz, n, d_in),
-                             state_quant.storage_dtype(state_dtype)),
-        jax.ShapeDtypeStruct((bsz, g), jnp.float32),
-    )
-    out_specs = (
-        pl.BlockSpec((1, block_d), lambda bb, dd: (bb, dd)),
-        pl.BlockSpec((1, n, block_d), lambda bb, dd: (bb, 0, dd)),
-        pl.BlockSpec((1, 1), lambda bb, dd: (bb, dd)),
-    )
+    def _pad_chan(t):
+        if t is None:
+            return None
+        return jnp.pad(t.astype(jnp.float32), (0, pad_d)).reshape(1, -1)
 
-    kernel = functools.partial(
-        _step_kernel_q, exp_impl=exp_impl, silu_impl=silu_impl,
-        has_d=has_d, has_z=has_z, state_dtype=state_dtype, wq=wq)
+    hp = jnp.pad(h.swapaxes(1, 2), ((0, pad_b), (0, 0), (0, pad_d)))
+    sp = (None if h_scale is None
+          else jnp.pad(h_scale, ((0, pad_b), (0, 0))).T[:, :, None, None])
+    at = jnp.pad(A, ((0, pad_d), (0, 0))).T                 # (n, Dp)
+    if a_scale is None:
+        at = at.astype(jnp.float32)
+    bp = jnp.pad(B_t, ((0, pad_b), (0, 0)))
+    cp = jnp.pad(C_t, ((0, pad_b), (0, 0)))
 
-    return pl.pallas_call(
-        kernel,
-        out_shape=out_shapes,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        compiler_params=pallas_compat.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
-        interpret=interpret,
-        name="marca_decode_step_q",
-    )(*args)
+    out = _step_padded(
+        hp, sp, _pad_row(x_t), _pad_row(dt_t), at, _pad_chan(a_scale), bp,
+        cp, _pad_chan(D), _pad_row(z_t), block_b=block_b, block_d=block_d,
+        exp_impl=exp_impl, silu_impl=silu_impl, state_dtype=state_dtype,
+        interpret=backend.resolve_interpret(interpret))
+    y, h_new = out[0][:bsz, :d_in], out[1][:bsz, :, :d_in].swapaxes(1, 2)
+    if h_scale is None:
+        return y, h_new
+    return y, h_new, out[2][:, :bsz, 0, 0].T
 
 
 # ---------------------------------------------------------------------------
@@ -437,8 +390,7 @@ def stacked_layer_launch(body, x0, stacked, out_structs, *,
 
     Returns (x_final, tuple(stacked_outs)).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = backend.resolve_interpret(interpret)
     leaves, treedef = jax.tree.flatten(stacked)
     n_layers = leaves[0].shape[0]
     for lf in leaves:
@@ -450,6 +402,12 @@ def stacked_layer_launch(body, x0, stacked, out_structs, *,
     def _const_map(l):
         return x_nz
 
+    # A block's last two dims must tile (8, 128) or span the array, so
+    # a per-layer leaf of rank < 2 (a norm scale, a bias) gets unit dims
+    # after the layer axis: (L, X) -> (L, 1, X) with block (1, 1, X).
+    lifted = [(1,) * max(0, 2 - (lf.ndim - 1)) for lf in leaves]
+    leaves = [lf.reshape((n_layers,) + u + lf.shape[1:])
+              for lf, u in zip(leaves, lifted)]
     in_specs = [pl.BlockSpec(x0.shape, _const_map)]
     for lf in leaves:
         rest = lf.shape[1:]
@@ -479,7 +437,8 @@ def stacked_layer_launch(body, x0, stacked, out_structs, *,
             x_ref[...] = x0_ref[...]
 
         x = x_ref[...]
-        ins = treedef.unflatten([r[0] for r in in_refs])
+        ins = treedef.unflatten([r[(0,) * (1 + len(u))]
+                                 for r, u in zip(in_refs, lifted)])
         x_new, outs = body(x, ins)
         x_ref[...] = x_new.astype(x_ref.dtype)
         for o_ref, o in zip(out_refs, outs):
@@ -491,12 +450,29 @@ def stacked_layer_launch(body, x0, stacked, out_structs, *,
         grid=(n_layers,),
         in_specs=in_specs,
         out_specs=tuple(out_specs),
-        compiler_params=pallas_compat.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=(None if interpret
+                              else backend.vmem_budget_bytes())),
         interpret=interpret,
         name=name,
     )(x0, *leaves)
     return res[0], tuple(res[1:])
+
+
+def stacked_layer_vmem_bytes(stacked, out_structs) -> int:
+    """VMEM one grid step of ``stacked_layer_launch`` needs, from its
+    operands: every per-layer input and output block double-buffered,
+    plus an f32 working copy of each input block — the body dequantizes
+    or casts weights and state to f32 (or from f32 to the compute
+    dtype) before it uses them.  ``stacked`` may hold arrays, tracers
+    or ShapeDtypeStructs with the leading layer axis; ``out_structs``
+    the per-layer output shapes, as the launcher takes them."""
+    ins = [(lf.shape[1:], lf.dtype) for lf in jax.tree.leaves(stacked)]
+    outs = [(s.shape, s.dtype) for s in out_structs]
+    return (2 * sum(backend.block_bytes(*b) for b in ins + outs)
+            + sum(backend.block_bytes(shape, jnp.float32)
+                  for shape, _ in ins))
 
 
 def selective_state_step_q(hq, h_scale, x_t, dt_t, A, B_t, C_t, D=None,
@@ -514,35 +490,16 @@ def selective_state_step_q(hq, h_scale, x_t, dt_t, A, B_t, C_t, D=None,
 
     The channel blocking is pinned to the scale grouping (block_d =
     min(D_BLOCK, d)), so dequant/requant stay local to one grid cell.
-    Note: int8/fp8 HBM tiles want (32, 128) alignment on real TPU; the
-    d_state sublane dim of small configs is below that, which costs
-    padding, not correctness."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    bsz, d_in, n = hq.shape
-    block_d = min(state_quant.D_BLOCK, d_in)
-    g = state_quant.n_groups(d_in)
-    pad_d = g * block_d - d_in
-    assert h_scale.shape == (bsz, g), (h_scale.shape, (bsz, g))
-
-    def _pad_row(t):
-        if t is None:
-            return None
-        return jnp.pad(t, ((0, 0), (0, pad_d)))
-
-    hp = jnp.pad(hq.swapaxes(1, 2), ((0, 0), (0, 0), (0, pad_d)))
-    at = jnp.pad(A.astype(jnp.float32), ((0, pad_d), (0, 0))).T  # (n, Dp)
-    asp = (None if a_scale is None
-           else jnp.pad(a_scale.astype(jnp.float32),
-                        (0, pad_d)).reshape(1, -1))
-    dp = (None if D is None
-          else jnp.pad(D.astype(jnp.float32), (0, pad_d)).reshape(1, -1))
-
-    y, hq_new, scale_new = _step_padded_q(
-        hp, h_scale, _pad_row(x_t), _pad_row(dt_t), at, asp, B_t, C_t, dp,
-        _pad_row(z_t), block_d=block_d, exp_impl=exp_impl,
+    The payload block's N-wide sublane dim is the whole array dim, which
+    the (32, 128) int8 tiling pads rather than rejects.  fp8 payloads
+    (float8_e4m3fn) compile for v5e too, though v5e has no fp8
+    arithmetic: the kernel only converts them to and from f32."""
+    g = state_quant.n_groups(hq.shape[1])
+    assert h_scale.shape == (hq.shape[0], g), (h_scale.shape, g)
+    return _launch_step(
+        hq, h_scale, x_t, dt_t, A, B_t, C_t, D, z_t, a_scale,
+        block_d=min(state_quant.D_BLOCK, hq.shape[1]), exp_impl=exp_impl,
         silu_impl=silu_impl, state_dtype=state_dtype, interpret=interpret)
-    return (y[:, :d_in], hq_new[:, :, :d_in].swapaxes(1, 2), scale_new)
 
 
 def selective_state_step(h, x_t, dt_t, A, B_t, C_t, D=None, z_t=None,
@@ -559,32 +516,12 @@ def selective_state_step(h, x_t, dt_t, A, B_t, C_t, D=None, z_t=None,
     matrix streams from HBM at one byte per entry.
     Returns (y (b, d) in x_t.dtype, h_new (b, d, n) f32).
 
-    ``interpret=None`` resolves per backend: compiled on TPU, the Pallas
-    interpreter elsewhere — so the serving hot path is never accidentally
-    interpreted on the hardware the kernel targets.
+    ``interpret=None`` resolves per backend (kernels.backend): compiled
+    on TPU, the Pallas interpreter elsewhere — so the serving hot path
+    is never accidentally interpreted on the hardware the kernel
+    targets.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    bsz, d_in, n = h.shape
-    block_d = min(block_d, d_in)
-    pad_d = (-d_in) % block_d
-
-    def _pad_row(t):
-        if t is None:
-            return None
-        return jnp.pad(t, ((0, 0), (0, pad_d)))
-
-    hp = jnp.pad(h.astype(jnp.float32).swapaxes(1, 2),
-                 ((0, 0), (0, 0), (0, pad_d)))                  # (b, n, Dp)
-    at = jnp.pad(A.astype(jnp.float32), ((0, pad_d), (0, 0))).T  # (n, Dp)
-    asp = (None if a_scale is None
-           else jnp.pad(a_scale.astype(jnp.float32),
-                        (0, pad_d)).reshape(1, -1))
-    dp = (None if D is None
-          else jnp.pad(D.astype(jnp.float32), (0, pad_d)).reshape(1, -1))
-
-    y, h_new = _step_padded(
-        hp, _pad_row(x_t), _pad_row(dt_t), at, asp, B_t, C_t, dp,
-        _pad_row(z_t), block_d=block_d, exp_impl=exp_impl,
-        silu_impl=silu_impl, interpret=interpret)
-    return y[:, :d_in], h_new[:, :, :d_in].swapaxes(1, 2)
+    return _launch_step(
+        h.astype(jnp.float32), None, x_t, dt_t, A, B_t, C_t, D, z_t,
+        a_scale, block_d=min(block_d, h.shape[1]), exp_impl=exp_impl,
+        silu_impl=silu_impl, state_dtype="f32", interpret=interpret)

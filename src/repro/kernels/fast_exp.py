@@ -13,14 +13,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
+from repro.kernels import backend
 
 from repro.core import approx
 
-_LANES = pallas_compat.LANES
-_DEFAULT_COLS = pallas_compat.DEFAULT_COLS
-_DEFAULT_ROWS = pallas_compat.DEFAULT_ROWS
+_LANES = backend.LANES
+_DEFAULT_COLS = backend.DEFAULT_COLS
+_DEFAULT_ROWS = backend.DEFAULT_ROWS
 
 
 def _fast_exp_kernel(x_ref, o_ref, *, b_shift: float, c: float):
@@ -34,7 +35,7 @@ def _fast_exp_kernel(x_ref, o_ref, *, b_shift: float, c: float):
                                              "cols", "interpret"))
 def fast_exp_2d(x, b_shift=approx.OUR_EXP_B_SHIFT, c=approx.OUR_EXP_C,
                 block_rows=_DEFAULT_ROWS, cols=_DEFAULT_COLS,
-                interpret=True):
+                interpret=None):
     """Element-wise biased exp over a 2D array (rows, cols)."""
     rows = x.shape[0]
     grid = (pl.cdiv(rows, block_rows),)
@@ -44,15 +45,15 @@ def fast_exp_2d(x, b_shift=approx.OUR_EXP_B_SHIFT, c=approx.OUR_EXP_C,
         grid=grid,
         in_specs=[pl.BlockSpec((block_rows, cols), lambda r: (r, 0))],
         out_specs=pl.BlockSpec((block_rows, cols), lambda r: (r, 0)),
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
-        interpret=interpret,
+        interpret=backend.resolve_interpret(interpret),
         name="marca_fast_exp",
     )(x)
 
 
 def fast_exp(x, b_shift=approx.OUR_EXP_B_SHIFT, c=approx.OUR_EXP_C,
-             interpret=True):
+             interpret=None):
     """Shape-polymorphic wrapper: flatten -> pad -> tile -> kernel -> unpad."""
     n = x.size
     cols = _DEFAULT_COLS if n >= _DEFAULT_COLS else _LANES

@@ -19,7 +19,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels import pallas_compat
+from repro.kernels import backend
 
 _NEG_INF = -1e30
 
@@ -70,7 +70,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 @functools.partial(jax.jit, static_argnames=(
     "block_q", "block_k", "causal", "scale", "q_offset", "interpret"))
 def _flash_bhld(q, k, v, block_q: int, block_k: int, causal: bool,
-                scale: float, q_offset: int, interpret: bool):
+                scale: float, q_offset: int, interpret: bool | None):
     """q (b, hq, lq, dh); k/v (b, hkv, lk, dh); lq % bq == lk % bk == 0."""
     b, hq, lq, dh = q.shape
     hkv = k.shape[1]
@@ -99,17 +99,17 @@ def _flash_bhld(q, k, v, block_q: int, block_k: int, causal: bool,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, dh), jnp.float32),
         ],
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-        interpret=interpret,
+        interpret=backend.resolve_interpret(interpret),
         name="flash_attention",
     )(q, k, v)
 
 
 def flash_attention(q, k, v, causal: bool = True, scale=None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """q (b, lq, hq, dh); k/v (b, lk, hkv, dh) — matches kernels.ref.attention.
 
     Handles lq < lk (q is the suffix of the sequence, decode-chunk style).

@@ -1,8 +1,8 @@
 """Public jit'd wrappers over the Pallas kernels with impl dispatch.
 
 Models call these; ``impl`` selects between the Pallas kernel ("pallas",
-interpret-mode on CPU, compiled on real TPU) and the pure-jnp oracle
-("xla").  The oracle is also what autodiff differentiates through for
+compiled on TPU and interpreted elsewhere — kernels.backend decides)
+and the pure-jnp oracle ("xla").  The oracle is also what autodiff differentiates through for
 training paths (the Pallas forward is inference/serving + perf analysis;
 see DESIGN.md §2).
 """
@@ -50,10 +50,8 @@ def selective_scan(x, dt, A, B, C, D=None, z=None, h0=None,
     if impl == "pallas_vjp":
         # trainable kernel path: custom VJP covers the recurrence core;
         # D-skip and z-gate stay in autodiff-able jnp
-        import jax
         assert h0 is None, "pallas_vjp path starts from h0=0 (training)"
-        y, h_last = _scan_k.selective_scan_trainable(x, dt, A, B, C,
-                                                     chunk, True)
+        y, h_last = _scan_k.selective_scan_trainable(x, dt, A, B, C, chunk)
         if D is not None:
             y = y + D.astype(jnp.float32)[None, None, :] \
                 * x.astype(jnp.float32)

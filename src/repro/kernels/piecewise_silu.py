@@ -13,14 +13,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
+from repro.kernels import backend
 
 from repro.core import approx
 
-_LANES = pallas_compat.LANES
-_DEFAULT_COLS = pallas_compat.DEFAULT_COLS
-_DEFAULT_ROWS = pallas_compat.DEFAULT_ROWS
+_LANES = backend.LANES
+_DEFAULT_COLS = backend.DEFAULT_COLS
+_DEFAULT_ROWS = backend.DEFAULT_ROWS
 
 
 def _silu_kernel(x_ref, o_ref, *, variant: str):
@@ -35,7 +36,7 @@ def _silu_kernel(x_ref, o_ref, *, variant: str):
 @functools.partial(jax.jit, static_argnames=("variant", "block_rows", "cols",
                                              "interpret"))
 def piecewise_silu_2d(x, variant="ours", block_rows=_DEFAULT_ROWS,
-                      cols=_DEFAULT_COLS, interpret=True):
+                      cols=_DEFAULT_COLS, interpret=None):
     rows = x.shape[0]
     grid = (pl.cdiv(rows, block_rows),)
     return pl.pallas_call(
@@ -44,14 +45,14 @@ def piecewise_silu_2d(x, variant="ours", block_rows=_DEFAULT_ROWS,
         grid=grid,
         in_specs=[pl.BlockSpec((block_rows, cols), lambda r: (r, 0))],
         out_specs=pl.BlockSpec((block_rows, cols), lambda r: (r, 0)),
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
-        interpret=interpret,
+        interpret=backend.resolve_interpret(interpret),
         name="marca_piecewise_silu",
     )(x)
 
 
-def piecewise_silu(x, variant="ours", interpret=True):
+def piecewise_silu(x, variant="ours", interpret=None):
     """Shape-polymorphic wrapper (flatten -> pad -> tile)."""
     n = x.size
     cols = _DEFAULT_COLS if n >= _DEFAULT_COLS else _LANES

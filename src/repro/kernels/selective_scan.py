@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels import pallas_compat
+from repro.kernels import backend
 
 from repro.core import approx
 
@@ -88,7 +88,8 @@ def _scan_kernel(x_ref, dt_ref, at_ref, b_ref, c_ref, d_ref, z_ref, h0_ref,
                      "interpret"))
 def _selective_scan_padded(x, dt, at, b, c, d_skip, z, h0,
                            block_d: int, block_l: int, l_true: int,
-                           exp_impl: str, silu_impl: str, interpret: bool):
+                           exp_impl: str, silu_impl: str,
+                           interpret: bool | None):
     """All inputs pre-padded: L % block_l == 0, D % block_d == 0."""
     bsz, L, d_in = x.shape
     n = at.shape[0]
@@ -142,9 +143,9 @@ def _selective_scan_padded(x, dt, at, b, c, d_skip, z, h0,
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[pltpu.VMEM((n, block_d), jnp.float32)],
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=backend.resolve_interpret(interpret),
         name="marca_selective_scan",
     )(*args)
     return y, h_last
@@ -153,7 +154,7 @@ def _selective_scan_padded(x, dt, at, b, c, d_skip, z, h0,
 def selective_scan(x, dt, A, B, C, D=None, z=None, h0=None,
                    block_d: int = 256, block_l: int = 128,
                    exp_impl: str = "exact", silu_impl: str = "exact",
-                   interpret: bool = True):
+                   interpret: bool | None = None):
     """Fused selective scan.  Same semantics as kernels.ref.selective_scan.
 
     x, dt: (b, L, d); A: (d, n); B, C: (b, L, n); D: (d,)|None;
@@ -241,7 +242,7 @@ def _fwd_boundaries(x, dt, A, B, C, chunk):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def selective_scan_trainable(x, dt, A, B, C, chunk: int = 128,
-                             interpret: bool = True):
+                             interpret: bool | None = None):
     """Recurrence core with kernel forward + memory-lean backward.
     x/dt (b,L,d); A (d,n); B/C (b,L,n) -> (y (b,L,d) f32, h_last f32)."""
     y, h_last = selective_scan(x, dt, A, B, C, interpret=interpret)

@@ -5,7 +5,10 @@ Multi-pod:  (pod=2, data=16, model=16) = 512 chips; the "pod" axis maps to
 DCN, so the sharding rules keep parameters off it (DESIGN.md §4).
 
 Defined as functions (never module-level constants) so importing this module
-never touches jax device state.
+never touches jax device state.  Every mesh has Auto axes: the models
+place arrays through logical sharding constraints and let GSPMD
+propagate the rest, which ``jax.make_mesh``'s default Explicit axes
+would instead turn into type errors (e.g. on the embedding gather).
 """
 from __future__ import annotations
 
@@ -24,7 +27,13 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {need} devices, have {len(devices)}; "
             "run under XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "(launch/dryrun.py does this automatically)")
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    return _auto_mesh(shape, axes, devices[:need])
+
+
+def _auto_mesh(shape, axes, devices):
+    return jax.make_mesh(
+        shape, axes, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(shape=(2, 2), axes=("data", "model")):
@@ -33,7 +42,7 @@ def make_local_mesh(shape=(2, 2), axes=("data", "model")):
     devices = jax.devices()
     if len(devices) < need:
         raise RuntimeError(f"need {need} devices, have {len(devices)}")
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    return _auto_mesh(shape, axes, devices[:need])
 
 
 def make_serving_mesh(tp: int = 2, axis: str = "model"):
@@ -52,4 +61,4 @@ def make_serving_mesh(tp: int = 2, axis: str = "model"):
             f"{len(devices)}; run under "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={tp} "
             "(CPU) or on a host with enough accelerators")
-    return jax.make_mesh((tp,), (axis,), devices=devices[:tp])
+    return _auto_mesh((tp,), (axis,), devices[:tp])

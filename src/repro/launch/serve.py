@@ -11,6 +11,7 @@ import time
 import jax
 
 from repro import configs
+from repro.launch import compile_cache
 from repro.checkpoint import CheckpointManager
 from repro.data import SyntheticLM
 from repro.models import registry
@@ -38,6 +39,7 @@ def main():
                     help="pooled decode-state storage dtype; int8 "
                          "multiplies slot capacity ~4x")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = configs.get_config(args.arch)
     if args.smoke:

@@ -19,6 +19,7 @@ import dataclasses
 import jax
 
 from repro import configs
+from repro.launch import compile_cache
 from repro.optim import AdamWConfig
 from repro.parallel import sharding
 from repro.runtime.train_loop import TrainConfig, Trainer
@@ -46,6 +47,7 @@ def main():
     ap.add_argument("--scan-impl", default=None)
     ap.add_argument("--dtype", default=None)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = configs.get_config(args.arch)
     over = {}

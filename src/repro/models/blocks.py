@@ -48,7 +48,11 @@ def dense(p, x, compute_dtype=None):
     if compute_dtype is not None:
         w = w.astype(compute_dtype)
         x = x.astype(compute_dtype)
-    y = x @ w
+    # f32 accumulation, rounded once to the operand dtype: what XLA does
+    # for a bf16 matmul anyway, and what a Pallas TPU body (the
+    # megakernels) must ask for explicitly
+    y = jnp.matmul(x, w, preferred_element_type=jnp.float32).astype(
+        jnp.result_type(x, w))
     if "b" in p:
         y = y + p["b"].astype(y.dtype)
     return y

@@ -53,6 +53,14 @@ def write_state_h(cfg, h, prev_state=None):
     return {"h": h.astype(state_quant.storage_dtype(cfg.state_dtype))}
 
 
+def _silu(cfg, x):
+    """SiLU of the conv output, computed in f32 and rounded once to
+    x's dtype — the same bits on every path, and the form a Pallas TPU
+    body (the megakernel) can lower for bf16 activations."""
+    return approx.get_silu(cfg.silu_impl)(x.astype(jnp.float32)).astype(
+        x.dtype)
+
+
 def mamba_block_init(cfg, key):
     d, di, n, k, r = (cfg.d_model, cfg.d_inner, cfg.d_state, cfg.d_conv,
                       cfg.dt_rank)
@@ -103,13 +111,12 @@ def _ssm_inputs(cfg, p, x_a):
 def mamba_block_apply(cfg, p, x, state=None):
     """Full-sequence path.  state (decode continuation) is a dict with
     'h' (b, di, n) f32 and 'conv' (b, k-1, di); returns (y, new_state)."""
-    silu = approx.get_silu(cfg.silu_impl)
     x_in, z = _project(cfg, p, x)
     conv_state = None if state is None else state["conv"]
     x_c, new_conv = ops.causal_conv1d(
         x_in, p["conv_w"], p["conv_b"], x_prev=conv_state,
         impl=cfg.conv_impl)
-    x_a = silu(x_c)
+    x_a = _silu(cfg, x_c)
     dt, B, C = _ssm_inputs(cfg, p, x_a)
     A, a_scale = _a_and_scale(p)
     if a_scale is not None:
@@ -141,12 +148,11 @@ def mamba_block_step(cfg, p, x_t, state):
     contraction, D-skip, and SiLU gate are one Pallas launch over the
     pooled batch instead of the per-op XLA chain."""
     from repro.core.selective_scan import resolve_cell_impl
-    silu = approx.get_silu(cfg.silu_impl)
     x_in, z = _project(cfg, p, x_t)             # (b,1,di)
     x_c, new_conv = ops.causal_conv1d(
         x_in, p["conv_w"], p["conv_b"], x_prev=state["conv"],
         impl=cfg.conv_impl)
-    x_a = silu(x_c)
+    x_a = _silu(cfg, x_c)
     dt, B, C = _ssm_inputs(cfg, p, x_a)
     A, a_scale = _a_and_scale(p)
     impl = resolve_cell_impl(cfg.step_impl)
@@ -185,11 +191,10 @@ def mamba_block_megastep(cfg, p, x_t, state):
     """
     from repro.kernels import decode_step as dsk
     from repro.kernels import ref as kref
-    silu = approx.get_silu(cfg.silu_impl)
     x_in, z = _project(cfg, p, x_t)             # (b,1,di)
     x_c, new_conv = kref.causal_conv1d(
         x_in, p["conv_w"], p["conv_b"], x_prev=state["conv"])
-    x_a = silu(x_c)
+    x_a = _silu(cfg, x_c)
     dt, B, C = _ssm_inputs(cfg, p, x_a)
     A, a_scale = _a_and_scale(p)
     wq = a_scale is not None
@@ -250,13 +255,12 @@ def mamba_block_verify(cfg, p, x, state):
     """
     from repro.core.selective_scan import (decode_scan, decode_scan_q,
                                            resolve_cell_impl)
-    silu = approx.get_silu(cfg.silu_impl)
     x_in, z = _project(cfg, p, x)                # (b,K,di)
     x_c, _ = ops.causal_conv1d(
         x_in, p["conv_w"], p["conv_b"], x_prev=state["conv"],
         impl=cfg.conv_impl)
     conv_all = _conv_tail_states(state["conv"], x_in)
-    x_a = silu(x_c)
+    x_a = _silu(cfg, x_c)
     dt, B, C = _ssm_inputs(cfg, p, x_a)
     A, a_scale = _a_and_scale(p)
     impl = resolve_cell_impl(cfg.step_impl)

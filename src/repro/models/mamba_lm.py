@@ -10,6 +10,7 @@ import jax.numpy as jnp
 
 from repro.core import state_quant
 from repro.models import blocks, mamba
+from repro.parallel import sharding
 from repro.parallel.sharding import Param, constrain
 
 
@@ -137,6 +138,24 @@ def draft_cache_merge(cfg, full, sub, n):
     return out
 
 
+def _megakernel_operands(cfg, p, cache):
+    """The megakernel's stacked per-layer inputs (weights + pooled
+    state, leading L axis) and its per-layer output structs."""
+    stacked_in = {"p": p["layers"], "h": cache["h"], "conv": cache["conv"]}
+    if _quantized(cfg):
+        stacked_in["h_scale"] = cache["h_scale"]
+    b = cache["h"].shape[1]
+    di, n, k = cfg.d_inner, cfg.d_state, cfg.d_conv
+    storage = state_quant.storage_dtype(cfg.state_dtype)
+    out_structs = [jax.ShapeDtypeStruct((b, di, n), storage)]
+    if _quantized(cfg):
+        out_structs.append(jax.ShapeDtypeStruct(
+            (b, state_quant.n_groups(di)), jnp.float32))
+    out_structs.append(
+        jax.ShapeDtypeStruct((b, k - 1, di), cache["conv"].dtype))
+    return stacked_in, out_structs
+
+
 def stacked_step(cfg, p, cache, batch):
     """Single-token decode as ONE Pallas launch for the whole stack.
 
@@ -152,10 +171,7 @@ def stacked_step(cfg, p, cache, batch):
     x0 = blocks.embed_apply(cfg, p["embed"], batch["tokens"], dtype)
     x0 = constrain(x0, "act_batch", None, "act_embed")
     quant = _quantized(cfg)
-
-    stacked_in = {"p": p["layers"], "h": cache["h"], "conv": cache["conv"]}
-    if quant:
-        stacked_in["h_scale"] = cache["h_scale"]
+    stacked_in, out_structs = _megakernel_operands(cfg, p, cache)
 
     def body(x, ins):
         state = {"h": ins["h"], "conv": ins["conv"]}
@@ -167,16 +183,6 @@ def stacked_step(cfg, p, cache, batch):
         x = constrain(x + y, "act_batch", "act_seq", "act_embed")
         return x, _pack_state(cfg, ns)
 
-    b = cache["h"].shape[1]
-    di, n, k = cfg.d_inner, cfg.d_state, cfg.d_conv
-    storage = state_quant.storage_dtype(cfg.state_dtype)
-    out_structs = [jax.ShapeDtypeStruct((b, di, n), storage)]
-    if quant:
-        out_structs.append(jax.ShapeDtypeStruct(
-            (b, state_quant.n_groups(di)), jnp.float32))
-    out_structs.append(
-        jax.ShapeDtypeStruct((b, k - 1, di), cache["conv"].dtype))
-
     h, stacked = dsk.stacked_layer_launch(
         body, x0, stacked_in, out_structs, name="marca_megakernel_mamba")
     h = blocks.apply_norm(cfg, p["norm_f"], h)
@@ -184,9 +190,24 @@ def stacked_step(cfg, p, cache, batch):
     return logits, _cache_from_stacked(cfg, stacked, cache["pos"] + 1)
 
 
-def decode_step(cfg, p, cache, batch):
+def decode_path(cfg, p, cache):
+    """The decode path ``decode_step`` runs for these operands:
+    cfg.step_impl resolved against the megakernel's VMEM need at the
+    served widths and pool size (core.selective_scan.resolve_step_impl).
+    Under a mesh there is no need to weigh: the megakernel's matmuls
+    span the sharded channels and a Mosaic kernel cannot be partitioned
+    automatically, while the per-layer kernel runs per channel shard."""
     from repro.core.selective_scan import resolve_step_impl
-    if resolve_step_impl(cfg.step_impl) == "megakernel":
+    from repro.kernels import decode_step as dsk
+    need = None
+    if sharding.active_mesh() is None:
+        need = dsk.stacked_layer_vmem_bytes(
+            *_megakernel_operands(cfg, p, cache))
+    return resolve_step_impl(cfg.step_impl, megakernel_vmem=need)
+
+
+def decode_step(cfg, p, cache, batch):
+    if decode_path(cfg, p, cache) == "megakernel":
         return stacked_step(cfg, p, cache, batch)
     dtype = jnp.dtype(cfg.dtype)
     h = blocks.embed_apply(cfg, p["embed"], batch["tokens"], dtype)

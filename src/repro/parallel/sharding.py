@@ -96,6 +96,11 @@ LONG_CONTEXT_OVERRIDES = dict(act_batch=None, act_seq="data")
 _CTX: dict = {"mesh": None, "rules": ShardingRules()}
 
 
+def active_mesh() -> Optional[Mesh]:
+    """The mesh of the enclosing ``use_mesh``/``shard_ctx``, or None."""
+    return _CTX["mesh"]
+
+
 def set_mesh_and_rules(mesh: Optional[Mesh], rules: Optional[ShardingRules]):
     _CTX["mesh"] = mesh
     _CTX["rules"] = rules or ShardingRules()
@@ -157,12 +162,8 @@ def constrain(x, *axes):
 
 def pcast_varying(x, axis_name: str):
     """Mark ``x`` varying over ``axis_name`` for shard_map's vma type
-    system.  On jax versions without lax.pcast (pre-vma) this is the
-    identity — values there are implicitly varying."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:
-        return x
-    return pcast(x, (axis_name,), to="varying")
+    system."""
+    return jax.lax.pcast(x, (axis_name,), to="varying")
 
 
 def is_param(x) -> bool:

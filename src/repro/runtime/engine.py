@@ -268,8 +268,8 @@ class EngineConfig:
     # jamba's attention sublayers stay on their own path), "fused" = one
     # kernel launch per layer per token for the SSM state-update/
     # contraction/gate chain, "xla" = unfused reference ops, None = keep
-    # the model config's setting ("auto" resolves per backend:
-    # megakernel on TPU).
+    # the model config's setting ("auto" resolves from the backend and
+    # the megakernel's VMEM need: core.selective_scan.resolve_step_impl).
     step_impl: Optional[str] = None
     # override for the pooled recurrent-state storage dtype
     # (cfg.state_dtype): "f32" | "bf16" | "int8" | "fp8".  int8/fp8
