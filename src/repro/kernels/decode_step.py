@@ -39,6 +39,14 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import approx, state_quant
 from repro.kernels import backend
 
+# ``jax.named_scope`` names around each decode kernel launch and the
+# recurrent-state staging that feeds it and writes it back, so ops in
+# a compiled program (and on a device trace) say which of the three
+# they belong to.
+SCOPE_KERNEL = "decode_kernel"
+SCOPE_STATE_IN = "decode_state_in"
+SCOPE_STATE_OUT = "decode_state_out"
+
 
 # ---------------------------------------------------------------------------
 # Cell skeleton — MARCA's reconfigurable PE, expressed as code.
@@ -339,24 +347,29 @@ def _launch_step(h, h_scale, x_t, dt_t, A, B_t, C_t, D, z_t, a_scale, *,
             return None
         return jnp.pad(t.astype(jnp.float32), (0, pad_d)).reshape(1, -1)
 
-    hp = jnp.pad(h.swapaxes(1, 2), ((0, pad_b), (0, 0), (0, pad_d)))
-    sp = (None if h_scale is None
-          else jnp.pad(h_scale, ((0, pad_b), (0, 0))).T[:, :, None, None])
+    with jax.named_scope(SCOPE_STATE_IN):
+        hp = jnp.pad(h.swapaxes(1, 2), ((0, pad_b), (0, 0), (0, pad_d)))
+        sp = (None if h_scale is None else
+              jnp.pad(h_scale, ((0, pad_b), (0, 0))).T[:, :, None, None])
     at = jnp.pad(A, ((0, pad_d), (0, 0))).T                 # (n, Dp)
     if a_scale is None:
         at = at.astype(jnp.float32)
     bp = jnp.pad(B_t, ((0, pad_b), (0, 0)))
     cp = jnp.pad(C_t, ((0, pad_b), (0, 0)))
 
-    out = _step_padded(
-        hp, sp, _pad_row(x_t), _pad_row(dt_t), at, _pad_chan(a_scale), bp,
-        cp, _pad_chan(D), _pad_row(z_t), block_b=block_b, block_d=block_d,
-        exp_impl=exp_impl, silu_impl=silu_impl, state_dtype=state_dtype,
-        interpret=backend.resolve_interpret(interpret))
-    y, h_new = out[0][:bsz, :d_in], out[1][:bsz, :, :d_in].swapaxes(1, 2)
-    if h_scale is None:
-        return y, h_new
-    return y, h_new, out[2][:, :bsz, 0, 0].T
+    with jax.named_scope(SCOPE_KERNEL):
+        out = _step_padded(
+            hp, sp, _pad_row(x_t), _pad_row(dt_t), at, _pad_chan(a_scale),
+            bp, cp, _pad_chan(D), _pad_row(z_t), block_b=block_b,
+            block_d=block_d, exp_impl=exp_impl, silu_impl=silu_impl,
+            state_dtype=state_dtype,
+            interpret=backend.resolve_interpret(interpret))
+    with jax.named_scope(SCOPE_STATE_OUT):
+        y = out[0][:bsz, :d_in]
+        h_new = out[1][:bsz, :, :d_in].swapaxes(1, 2)
+        if h_scale is None:
+            return y, h_new
+        return y, h_new, out[2][:, :bsz, 0, 0].T
 
 
 # ---------------------------------------------------------------------------

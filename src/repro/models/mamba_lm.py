@@ -183,8 +183,13 @@ def stacked_step(cfg, p, cache, batch):
         x = constrain(x + y, "act_batch", "act_seq", "act_embed")
         return x, _pack_state(cfg, ns)
 
-    h, stacked = dsk.stacked_layer_launch(
-        body, x0, stacked_in, out_structs, name="marca_megakernel_mamba")
+    # the pooled state goes in and out as the launch's own operands and
+    # results: no staging op of this code's feeds it, so the kernel is
+    # the one scope here
+    with jax.named_scope(dsk.SCOPE_KERNEL):
+        h, stacked = dsk.stacked_layer_launch(
+            body, x0, stacked_in, out_structs,
+            name="marca_megakernel_mamba")
     h = blocks.apply_norm(cfg, p["norm_f"], h)
     logits = blocks.unembed_apply(cfg, p.get("unembed", {}), p["embed"], h)
     return logits, _cache_from_stacked(cfg, stacked, cache["pos"] + 1)

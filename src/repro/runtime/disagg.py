@@ -93,7 +93,9 @@ class PrefillWorker:
                       params=params, seed=seed, max_new=params.max_new,
                       stop_ids=frozenset(params.stop))
         try:
-            tok, lp, tv, ti, _ = eng._admit_into_slot(req, slot)
+            with eng._span("engine.admit", req.req_id) as span:
+                req.t_admit = span.t0
+                tok, lp, tv, ti, _ = eng._admit_into_slot(req, slot)
             state = snapshot_to_host(eng.pool.read([slot]))
         finally:
             eng.pool.evict(slot)

@@ -87,6 +87,16 @@ scheduler's degradation knob (clamps speculative depth under load
 without retracing).  A raising ``stream_cb`` no longer propagates into
 the scheduler loop: the engine counts it, drops the callback, and
 auto-cancels that request — co-resident streams are untouched.
+
+Host spans (runtime/tracing.py): ``step`` records ``engine.step``
+around all of it, ``engine.admit`` (request id) around each admission
+with ``engine.admit.prefill`` (the prefill dispatch and prefix-cache
+inserts) and ``engine.admit.sync`` (the first token's host read) inside,
+``engine.burst`` around each decode burst with its ``prepare``,
+``dispatch``, ``sync`` and ``deliver`` phases, ``engine.evict`` (request
+id) around a slot's eviction, ``engine.spec_pass`` and
+``engine.flush_pending``.  A request's ``t_admit`` and ``t_first`` and
+the ServeStats prefill and decode times are these spans' stamps.
 """
 from __future__ import annotations
 
@@ -105,7 +115,7 @@ import numpy as np
 from repro.models import registry
 from repro.parallel import sharding
 from repro.runtime import metrics as metrics_lib
-from repro.runtime import sampling
+from repro.runtime import sampling, tracing
 from repro.runtime.prefix_cache import (PrefixCache, PrefixCacheConfig,
                                         snapshot_to_device)
 from repro.runtime.sampling import SamplingParams
@@ -139,6 +149,8 @@ def _jit_prefill_admit(cfg, shard=None):
     from without re-running the prefill."""
     cax = registry.cache_axes(cfg) if shard is not None else None
 
+    # the program's name on a device trace is jit__fn, as the decode
+    # step's is jit__decode_fn: trace readers find them by these names
     def _fn(p, fresh, tokens, pool_cache, slot_id, sp, step):
         sampling.TRACE_COUNTS["prefill_admit"] += 1
         with sharding.shard_ctx(shard):
@@ -164,7 +176,7 @@ def _jit_prefill_prefix(cfg, shard=None):
     sampling: the snapshot is position-complete state, nothing else."""
     cax = registry.cache_axes(cfg) if shard is not None else None
 
-    def _fn(p, fresh, tokens):
+    def prefill_prefix(p, fresh, tokens):
         sampling.TRACE_COUNTS["prefill_prefix"] += 1
         with sharding.shard_ctx(shard):
             _, sub = registry.prefill(cfg, p, fresh, {"tokens": tokens})
@@ -173,7 +185,7 @@ def _jit_prefill_prefix(cfg, shard=None):
                 # leaves stay on "model" — restores scatter shard-local
                 sub = sharding.constrain_tree(sub, cax)
         return sub
-    return jax.jit(_fn)
+    return jax.jit(prefill_prefix)
 
 
 @functools.lru_cache(maxsize=None)
@@ -189,7 +201,7 @@ def _jit_suffix_admit(cfg, m: int, shard=None):
     suffix length (same discipline as the exact-length prefill)."""
     cax = registry.cache_axes(cfg) if shard is not None else None
 
-    def _fn(p, snap, toks, pool_cache, slot_id, sp, step):
+    def suffix_admit(p, snap, toks, pool_cache, slot_id, sp, step):
         sampling.TRACE_COUNTS["suffix_admit"] += 1
         with sharding.shard_ctx(shard):
             def body(c, tok_t):
@@ -209,7 +221,7 @@ def _jit_suffix_admit(cfg, m: int, shard=None):
             tok = sampling.sample(last, sp, step)
             lp, tv, ti = sampling.token_logprobs(last, tok)
         return tok[:, None], lp, tv, ti, last, new_pool, caches
-    return jax.jit(_fn)
+    return jax.jit(suffix_admit)
 
 
 @functools.lru_cache(maxsize=None)
@@ -347,10 +359,17 @@ class Request:
     snapshot: Optional[object] = dataclasses.field(default=None,
                                                    repr=False)
     tokens: list = dataclasses.field(default_factory=list)
+    # timings, on the engine's clock.  t_arrival: the request entered
+    # the system (the front end or scheduler that took it, else
+    # submit) — TTFT and latency count from here; t_submit: it entered
+    # this engine; t_admit: start of its ``engine.admit`` span;
+    # t_first: its first token reached the host
+    t_arrival: float = 0.0
     t_submit: float = 0.0
-    t_admit: Optional[float] = None       # prefill start
-    t_first: Optional[float] = None       # first token out (TTFT anchor)
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
     t_done: Optional[float] = None
+    slot: Optional[int] = None            # pool slot, once admitted
     # per-slot speculative-depth bookkeeping (spec decode only): how
     # many target passes this request's slot took and how many drafted
     # tokens were accepted — accepted/passes is the request's realized
@@ -383,7 +402,6 @@ class Request:
 
 class Engine:
     def __init__(self, cfg, params, ecfg: EngineConfig,
-                 logger: Optional[metrics_lib.MetricsLogger] = None,
                  clock: Callable[[], float] = time.perf_counter):
         if cfg.frontend in ("audio_stub", "vision_stub"):
             raise NotImplementedError(
@@ -482,7 +500,6 @@ class Engine:
             x.shape != y.shape for x, y in
             zip(jax.tree.leaves(a), jax.tree.leaves(b)))
         self.stats = metrics_lib.ServeStats()
-        self.logger = logger
         self._now = clock
         self._prefill = _jit_prefill_admit(cfg, self._shard)
         self._decode = _jit_decode_sample(cfg, self._shard)
@@ -510,7 +527,8 @@ class Engine:
                priority: int = 0,
                stream_cb: Optional[Callable] = None,
                tenant: Optional[str] = None,
-               session: bool = False) -> Request:
+               session: bool = False,
+               t_arrival: Optional[float] = None) -> Request:
         """Enqueue a request.
 
         params: per-request SamplingParams (None = the engine's
@@ -537,7 +555,11 @@ class Engine:
           O(d_inner * d_state) block decodes forever in constant
           bytes, which is exactly what per-position KV strips cannot
           do, so jamba-style hybrids are refused up front.
+        t_arrival: when the request entered the system, on this
+          engine's clock (a front end or scheduler that held it first
+          passes its own stamp); None = now.
         """
+        t_submit = self._now()
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size < 1:
             raise ValueError("empty prompt")
@@ -579,7 +601,9 @@ class Engine:
                       seed=seed, max_new=params.max_new,
                       stop_ids=frozenset(params.stop), eos_id=eos_id,
                       priority=priority, stream_cb=stream_cb,
-                      arrival=arrival or 0.0, t_submit=self._now(),
+                      arrival=arrival or 0.0, t_submit=t_submit,
+                      t_arrival=(t_submit if t_arrival is None
+                                 else t_arrival),
                       tenant=tenant, session=session)
         self._by_id[req_id] = req
         if arrival is None:
@@ -601,7 +625,8 @@ class Engine:
                         priority: int = 0,
                         stream_cb: Optional[Callable] = None,
                         tenant: Optional[str] = None,
-                        session: bool = False) -> Request:
+                        session: bool = False,
+                        t_arrival: Optional[float] = None) -> Request:
         """Enqueue a request whose prompt was already prefilled by a
         disaggregated prefill worker (runtime/disagg.py).
 
@@ -618,8 +643,9 @@ class Engine:
 
         The snapshot must come from a compatible engine: same model
         config and state/kv dtypes (checked structurally against the
-        pool's cache leaves).
+        pool's cache leaves).  ``t_arrival`` as in ``submit``.
         """
+        t_submit = self._now()
         prompt = np.asarray(snap.prompt, np.int32).reshape(-1)
         params = snap.params
         params.validate()
@@ -650,7 +676,9 @@ class Engine:
                       seed=snap.seed, max_new=params.max_new,
                       stop_ids=frozenset(params.stop),
                       priority=priority, stream_cb=stream_cb,
-                      arrival=arrival or 0.0, t_submit=self._now(),
+                      arrival=arrival or 0.0, t_submit=t_submit,
+                      t_arrival=(t_submit if t_arrival is None
+                                 else t_arrival),
                       tenant=tenant, session=session, snapshot=snap)
         self._by_id[req_id] = req
         if arrival is None:
@@ -693,9 +721,6 @@ class Engine:
         self.stats.record_cancelled()
         self._finished.append(req)
         self._by_id.pop(req.req_id, None)
-        if self.logger:
-            self.logger.log(event="cancel", req=req.req_id, slot=None,
-                            n_tokens=len(req.tokens))
 
     def _sweep_cancelled(self) -> bool:
         """Reclaim every cancelled request at a sync point: evict
@@ -746,9 +771,6 @@ class Engine:
         except Exception:
             self.stats.n_callback_errors += 1
             req.stream_cb = None
-            if self.logger:
-                self.logger.log(event="stream_cb_error", req=req.req_id,
-                                n_tokens=len(req.tokens))
             if not req.finished and not req.cancelled:
                 self.cancel(req.req_id)
 
@@ -766,9 +788,14 @@ class Engine:
             req.top_logprobs.append(
                 [(int(ti[i]), float(tv[i])) for i in range(k)])
 
+    def _span(self, name: str, req: Optional[int] = None):
+        """A host span (runtime/tracing.py) on this engine's clock."""
+        return tracing.span(name, req, self._now)
+
     def _admit_into_slot(self, req: Request, slot: int):
         """Prefill ``req``'s prompt into ``slot`` (params row already
-        set), consulting the prefix cache when enabled.  Cache hit:
+        set, ``req.t_admit`` stamped by the caller's ``engine.admit``
+        span), consulting the prefix cache when enabled.  Cache hit:
         restore the deepest cached block-boundary snapshot and chain
         only the suffix through the decode-step micro-scan.  Cold (with
         a usable boundary): prefill the first block once, then chain
@@ -777,74 +804,72 @@ class Engine:
         Returns (tok, lp, tv_row, ti_row, last_logits) with the first
         three host-side and ``last_logits`` the device (1, V) logits
         best-of-n samples its remaining branches' first tokens from."""
-        t0 = self._now()
-        req.t_admit = t0
         prompt = req.prompt
         length = int(prompt.size)
         pc = self._prefix
-        sp_row = self.pool.params.row(slot)
-        step0 = jnp.zeros((1,), jnp.int32)
-        slot_arr = jnp.asarray([slot])
         hit = None
-        snap = None
         p_from = 0
         bound = pc.boundary(length) if pc is not None else 0
-        if pc is not None and bound > 0:
-            hit = pc.lookup(prompt)
-            if hit is not None:
-                p_from, snap = hit
-            else:
-                # cold: one fixed-block-length prefill seeds the first
-                # snapshot; the suffix scan below computes the rest
-                p_from = pc.cfg.block
-                snap = self._prefill_prefix(
+        with self._span("engine.admit.prefill"):
+            sp_row = self.pool.params.row(slot)
+            step0 = jnp.zeros((1,), jnp.int32)
+            slot_arr = jnp.asarray([slot])
+            snap = None
+            if pc is not None and bound > 0:
+                hit = pc.lookup(prompt)
+                if hit is not None:
+                    p_from, snap = hit
+                else:
+                    # cold: one fixed-block-length prefill seeds the
+                    # first snapshot; the suffix scan below computes the
+                    # rest
+                    p_from = pc.cfg.block
+                    snap = self._prefill_prefix(
+                        self.prefill_params, self.pool.fresh,
+                        jnp.asarray(prompt[None, :p_from]))
+                    pc.insert(prompt[:p_from], snap)
+            if snap is None:
+                tok_dev, lp, tv, ti, last, new_pool = self._prefill(
                     self.prefill_params, self.pool.fresh,
-                    jnp.asarray(prompt[None, :p_from]))
-                pc.insert(prompt[:p_from], snap)
-        if snap is None:
-            tok_dev, lp, tv, ti, last, new_pool = self._prefill(
-                self.prefill_params, self.pool.fresh,
-                jnp.asarray(prompt[None]),
-                self.pool.cache, slot_arr, sp_row, step0)
-            self.pool.cache = new_pool
-        else:
-            m = length - p_from
-            fn = _jit_suffix_admit(self.cfg, m, self._shard)
-            tok_dev, lp, tv, ti, last, new_pool, chain = fn(
-                self.prefill_params, snap,
-                jnp.asarray(prompt[None, p_from:]),
-                self.pool.cache, slot_arr, sp_row, step0)
-            self.pool.cache = new_pool
-            # chain index j is the state after prompt[:p_from + j + 1]
-            for p in range(p_from + pc.cfg.block, bound + 1,
-                           pc.cfg.block):
-                pc.insert(prompt[:p],
-                          jax.tree.map(
-                              lambda leaf, j=p - p_from - 1: leaf[j],
-                              chain))
+                    jnp.asarray(prompt[None]),
+                    self.pool.cache, slot_arr, sp_row, step0)
+                self.pool.cache = new_pool
+            else:
+                m = length - p_from
+                fn = _jit_suffix_admit(self.cfg, m, self._shard)
+                tok_dev, lp, tv, ti, last, new_pool, chain = fn(
+                    self.prefill_params, snap,
+                    jnp.asarray(prompt[None, p_from:]),
+                    self.pool.cache, slot_arr, sp_row, step0)
+                self.pool.cache = new_pool
+                # chain index j is the state after prompt[:p_from + j + 1]
+                for p in range(p_from + pc.cfg.block, bound + 1,
+                               pc.cfg.block):
+                    pc.insert(prompt[:p],
+                              jax.tree.map(
+                                  lambda leaf, j=p - p_from - 1: leaf[j],
+                                  chain))
         if pc is not None and bound > 0:
             self.stats.record_prefix(hit is not None,
                                      p_from if hit is not None else 0)
         n_computed = length - (p_from if hit is not None else 0)
-        tok = int(np.asarray(tok_dev)[0, 0])
-        req.t_first = self._now()
+        with self._span("engine.admit.sync") as sync:
+            tok_h, lp_h, tv_h, ti_h = jax.device_get((tok_dev, lp, tv, ti))
+        req.t_first = sync.t1
         # prefill_tokens stays the honest COMPUTE count: restored-from-
         # cache tokens land in prefix_cached_tokens instead, which is
         # what the bench gate's strict-reduction assertion diffs
-        self.stats.record_prefill(n_computed, req.t_first - t0)
-        return (tok, float(np.asarray(lp)[0]), np.asarray(tv)[0],
-                np.asarray(ti)[0], last)
+        self.stats.record_prefill(n_computed, req.t_first - req.t_admit)
+        return (int(tok_h[0, 0]), float(lp_h[0]), tv_h[0], ti_h[0], last)
 
     def _install(self, req: Request, slot: int, tok: int, lp, tv,
                  ti) -> None:
         """Bind an admitted request to its slot and deliver its first
         token (shared tail of plain and best-of-n admission)."""
         self._slot_req[slot] = req
+        req.slot = slot
         self._next_tok[slot, 0] = tok
         self._append_token(req, tok, lp, tv, ti)
-        if self.logger:
-            self.logger.log(event="admit", req=req.req_id, slot=slot,
-                            prompt_len=int(req.prompt.size))
         if self._hit_stop(req):
             self._finish(slot)
         self._deliver(req, [tok])
@@ -857,27 +882,31 @@ class Engine:
         restore uses — then install the worker's first token.  No local
         prefill ran, so prefill_tokens is untouched; the transfer is
         accounted in the snapshot_* counters."""
-        t0 = self._now()
-        req.t_admit = t0
         snap = req.snapshot
-        self.pool.admit(slot, snapshot_to_device(snap.state))
-        req.t_first = self._now()
+        with self._span("engine.admit.prefill") as restore:
+            self.pool.admit(slot, snapshot_to_device(snap.state))
+        req.t_first = restore.t1
         self.stats.record_snapshot_admit(n_tokens=int(req.prompt.size),
                                          nbytes=snap.nbytes)
         return snap.tok, snap.lp, np.asarray(snap.tv), np.asarray(snap.ti)
 
     def _admit(self, req: Request) -> None:
-        slot = self.pool.alloc()
-        assert slot is not None
-        self.pool.params.set(slot, req.params, req.seed)
-        if req.snapshot is not None:
-            tok, lp, tv, ti = self._admit_snapshot_into_slot(req, slot)
-        else:
-            tok, lp, tv, ti, _ = self._admit_into_slot(req, slot)
-        if req.session:
-            # eviction-free lease: _finish unpins before evicting
-            self.pool.pin(slot)
-        self._install(req, slot, tok, lp, tv, ti)
+        """One admission, inside the ``engine.admit`` span: slot and
+        params row, prefill (or snapshot restore) and the first token's
+        host read, then ``_install``."""
+        with self._span("engine.admit", req.req_id) as span:
+            req.t_admit = span.t0
+            slot = self.pool.alloc()
+            assert slot is not None
+            self.pool.params.set(slot, req.params, req.seed)
+            if req.snapshot is not None:
+                tok, lp, tv, ti = self._admit_snapshot_into_slot(req, slot)
+            else:
+                tok, lp, tv, ti, _ = self._admit_into_slot(req, slot)
+            if req.session:
+                # eviction-free lease: _finish unpins before evicting
+                self.pool.pin(slot)
+            self._install(req, slot, tok, lp, tv, ti)
 
     def _branch_request(self, parent: Request, b: int) -> Request:
         """Child request for branch ``b`` of a best-of-n parent.  The
@@ -889,47 +918,50 @@ class Engine:
             params=dataclasses.replace(parent.params, n=1),
             seed=parent.seed, max_new=parent.max_new,
             stop_ids=parent.stop_ids, eos_id=parent.eos_id,
-            priority=parent.priority, t_submit=parent.t_submit,
+            priority=parent.priority, t_arrival=parent.t_arrival,
+            t_submit=parent.t_submit, t_admit=parent.t_admit,
             branch=b, parent=parent)
         self._next_id += 1
         self._by_id[child.req_id] = child
         return child
 
     def _admit_group(self, parent: Request) -> None:
-        """Best-of-n admission: ONE prefill into the first slot, then
-        one fused fork into the remaining n-1 slots with branch tags
-        1..n-1 (each branch's key = fold_in(parent key, branch) — the
-        fork-seed aliasing fix), then each remaining branch's first
-        token sampled from the prefill's last-position logits under its
-        own folded key.  Branch 0 keeps the parent's verbatim key, so
-        its stream is bitwise the same request served at n=1."""
-        n = parent.params.n
-        slots = [self.pool.alloc() for _ in range(n)]
-        assert all(s is not None for s in slots)
-        children = [self._branch_request(parent, b) for b in range(n)]
-        parent.branches = list(children)
-        parent._open = n
-        self.pool.params.set(slots[0], parent.params, parent.seed)
-        tok0, lp0, tv0, ti0, last = self._admit_into_slot(children[0],
-                                                          slots[0])
-        parent.t_admit = children[0].t_admit
-        parent.t_first = children[0].t_first
-        # fork BEFORE any stop/cancel handling can evict slot 0: every
-        # branch needs its post-prompt state (and its params row, which
-        # the tagged copy re-keys)
-        self.pool.fork([slots[0]] * (n - 1), slots[1:],
-                       branch_tags=list(range(1, n)))
-        firsts = [(tok0, lp0, tv0, ti0)]
-        for b in range(1, n):
-            row = self.pool.params.row(slots[b])
-            tb = sampling.sample(last, row, jnp.zeros((1,), jnp.int32))
-            lb, tvb, tib = sampling.token_logprobs(last, tb)
-            firsts.append((int(np.asarray(tb)[0]),
-                           float(np.asarray(lb)[0]),
-                           np.asarray(tvb)[0], np.asarray(tib)[0]))
-        for b in range(n):
-            tok, lp, tv, ti = firsts[b]
-            self._install(children[b], slots[b], tok, lp, tv, ti)
+        """Best-of-n admission, one ``engine.admit`` span under the
+        parent's id: ONE prefill into the first slot, then one fused
+        fork into the remaining n-1 slots with branch tags 1..n-1 (each
+        branch's key = fold_in(parent key, branch) — the fork-seed
+        aliasing fix), then each remaining branch's first token sampled
+        from the prefill's last-position logits under its own folded
+        key.  Branch 0 keeps the parent's verbatim key, so its stream
+        is bitwise the same request served at n=1."""
+        with self._span("engine.admit", parent.req_id) as span:
+            parent.t_admit = span.t0
+            n = parent.params.n
+            slots = [self.pool.alloc() for _ in range(n)]
+            assert all(s is not None for s in slots)
+            children = [self._branch_request(parent, b) for b in range(n)]
+            parent.branches = list(children)
+            parent._open = n
+            self.pool.params.set(slots[0], parent.params, parent.seed)
+            tok0, lp0, tv0, ti0, last = self._admit_into_slot(children[0],
+                                                              slots[0])
+            parent.t_first = children[0].t_first
+            # fork BEFORE any stop/cancel handling can evict slot 0:
+            # every branch needs its post-prompt state (and its params
+            # row, which the tagged copy re-keys)
+            self.pool.fork([slots[0]] * (n - 1), slots[1:],
+                           branch_tags=list(range(1, n)))
+            firsts = [(tok0, lp0, tv0, ti0)]
+            for b in range(1, n):
+                row = self.pool.params.row(slots[b])
+                tb = sampling.sample(last, row, jnp.zeros((1,), jnp.int32))
+                lb, tvb, tib = sampling.token_logprobs(last, tb)
+                firsts.append((int(np.asarray(tb)[0]),
+                               float(np.asarray(lb)[0]),
+                               np.asarray(tvb)[0], np.asarray(tib)[0]))
+            for b in range(n):
+                tok, lp, tv, ti = firsts[b]
+                self._install(children[b], slots[b], tok, lp, tv, ti)
 
     def _hit_stop(self, req: Request) -> bool:
         # a session has no token horizon: only stops / cancel end it
@@ -957,22 +989,19 @@ class Engine:
         elif req.cancelled:
             self.stats.record_cancelled()
         else:
-            self.stats.record_request(ttft=req.t_first - req.t_submit,
-                                      latency=req.t_done - req.t_submit,
+            self.stats.record_request(ttft=req.t_first - req.t_arrival,
+                                      latency=req.t_done - req.t_arrival,
                                       n_tokens=len(req.tokens),
                                       tenant=req.tenant)
-        if req.session:
-            self.pool.unpin(slot)
-        self.pool.evict(slot)
+        with self._span("engine.evict", req.req_id):
+            if req.session:
+                self.pool.unpin(slot)
+            self.pool.evict(slot)
         self._slot_req[slot] = None
         self._next_tok[slot, 0] = 0
         if req.parent is None:
             self._finished.append(req)
         self._by_id.pop(req.req_id, None)
-        if self.logger:
-            self.logger.log(
-                event="cancel" if req.cancelled else "finish",
-                req=req.req_id, slot=slot, n_tokens=len(req.tokens))
         if req.parent is not None:
             self._child_done(req)
 
@@ -999,15 +1028,11 @@ class Engine:
             self.stats.record_cancelled()
         else:
             self.stats.record_request(
-                ttft=parent.t_first - parent.t_submit,
-                latency=parent.t_done - parent.t_submit,
+                ttft=parent.t_first - parent.t_arrival,
+                latency=parent.t_done - parent.t_arrival,
                 n_tokens=len(parent.tokens), tenant=parent.tenant)
         self._finished.append(parent)
         self._by_id.pop(parent.req_id, None)
-        if self.logger:
-            self.logger.log(event="finish_group", req=parent.req_id,
-                            n=len(kids), best=best.branch,
-                            n_tokens=len(parent.tokens))
 
     def _base_steps(self, active) -> np.ndarray:
         """Per-slot stream positions at sync start: tokens already
@@ -1062,28 +1087,47 @@ class Engine:
         return max(1, remaining)
 
     def _decode_burst(self) -> None:
-        active = self.pool.active_slots()
-        n_steps = self._burst_len(active)
-        t0 = self._now()
-        toks = jnp.asarray(self._next_tok)
-        act = jnp.asarray(self.pool.active_mask())
-        sp = self.pool.params.device()
-        base = jnp.asarray(self._base_steps(active))
-        cache = self.pool.cache
-        outs, lps, tvs, tis = [], [], [], []
-        for t in range(n_steps):
-            toks, lp, tv, ti, cache = self._decode(self.params, cache,
-                                                   toks, act, sp,
-                                                   base + t)
-            outs.append(toks)
-            lps.append(lp)
-            tvs.append(tv)
-            tis.append(ti)
-        self.pool.cache = cache
-        # one host sync per burst; device_get on the lists avoids
-        # compiling an XLA concatenate per distinct burst length
-        outs_h, lp_h, tv_h, ti_h = jax.device_get((outs, lps, tvs, tis))
-        burst = np.concatenate(outs_h, axis=1)
+        """One ``engine.burst``: prepare the step inputs, enqueue
+        ``n_steps`` chained decode steps, one host sync, then deliver
+        (token append, stop checks, callbacks, evictions)."""
+        with self._span("engine.burst") as span:
+            with self._span("engine.burst.prepare"):
+                active = self.pool.active_slots()
+                n_steps = self._burst_len(active)
+                toks = jnp.asarray(self._next_tok)
+                act = jnp.asarray(self.pool.active_mask())
+                sp = self.pool.params.device()
+                base = jnp.asarray(self._base_steps(active))
+            with self._span("engine.burst.dispatch"):
+                cache = self.pool.cache
+                outs, lps, tvs, tis = [], [], [], []
+                for t in range(n_steps):
+                    toks, lp, tv, ti, cache = self._decode(
+                        self.params, cache, toks, act, sp, base + t)
+                    outs.append(toks)
+                    lps.append(lp)
+                    tvs.append(tv)
+                    tis.append(ti)
+                self.pool.cache = cache
+            # one host sync per burst; device_get on the lists avoids
+            # compiling an XLA concatenate per distinct burst length
+            with self._span("engine.burst.sync"):
+                outs_h, lp_h, tv_h, ti_h = jax.device_get(
+                    (outs, lps, tvs, tis))
+            with self._span("engine.burst.deliver"):
+                n_appended = self._deliver_burst(
+                    active, n_steps, np.concatenate(outs_h, axis=1), lp_h,
+                    tv_h, ti_h)
+        self.stats.record_decode(n_active=len(active),
+                                 n_slots=self.ecfg.n_slots,
+                                 dt=span.t1 - span.t0,
+                                 n_steps=n_steps, n_tokens=n_appended)
+
+    def _deliver_burst(self, active, n_steps: int, burst, lp_h, tv_h,
+                       ti_h) -> int:
+        """Append each active slot's burst tokens up to its first stop,
+        stream them, and retire finished or cancelled requests.
+        Returns the tokens appended."""
         n_appended = 0
         for slot in active:
             req = self._slot_req[slot]
@@ -1101,10 +1145,7 @@ class Engine:
             self._deliver(req, new_toks)
             if req.cancelled and not req.finished:
                 self._finish(slot)
-        self.stats.record_decode(n_active=len(active),
-                                 n_slots=self.ecfg.n_slots,
-                                 dt=self._now() - t0,
-                                 n_steps=n_steps, n_tokens=n_appended)
+        return n_appended
 
     # ------------------------------------------------------------------
     # Speculative decoding (EngineConfig.draft)
@@ -1153,76 +1194,76 @@ class Engine:
             # decode burst (its own burst-length logic handles this)
             self._decode_burst()
             return
-        t0 = self._now()
-        leases: list[int] = []
-        try:
-            for _ in active:
-                sc = self.pool.lease_scratch()
-                assert sc is not None        # n_scratch == n_slots
-                leases.append(sc)
-            # branch_tags deliberately None: the draft scratch slot must
-            # continue the request's EXACT key schedule (fork copies the
-            # key verbatim) or spec decode loses its faithfulness
-            # contract — only best-of-n forks tag
-            self.pool.fork(active, leases)   # state + sampling params
-            total = self.pool.n_total
-            toks = np.zeros((total, 1), np.int32)
-            toks[leases, 0] = self._next_tok[active, 0]
-            scratch_mask = np.zeros((total,), bool)
-            scratch_mask[leases] = True
-            base = self._base_steps(active)
-            base[leases] = base[active]      # draft keys mirror live
-            limit = np.full((total,), k_eff, np.int32)
-            for s in active:
-                limit[s] = min(depths[s], k_eff)
-            sp = self.pool.params.device()
-            cache, d_toks, d_logits = spec.propose(
-                self.pool.cache, jnp.asarray(toks),
-                jnp.asarray(scratch_mask), sp, jnp.asarray(base), k_eff)
-            # proposals were drafted at scratch rows; the verify wants
-            # them at their live slots' rows
-            perm = np.arange(total)
-            perm[active] = leases
-            perm = jnp.asarray(perm)
-            emit, n_acc, _, snap, v_lp, v_tv, v_ti = spec.verify(
-                self.params, cache, jnp.asarray(self._next_tok),
-                d_toks[:, perm], d_logits[:, perm],
-                jnp.asarray(self.pool.active_mask()), sp,
-                jnp.asarray(base), jnp.asarray(limit))
-            # the rollback: every live slot's row of ``snap`` is the
-            # state after exactly its accepted prefix
-            self.pool.cache = snap
-            emit_h, n_acc_h = np.asarray(emit), np.asarray(n_acc)
-            lp_h, tv_h, ti_h = (np.asarray(v_lp), np.asarray(v_tv),
-                                np.asarray(v_ti))
-        finally:
-            for sc in leases:
-                self.pool.release_scratch(sc)
-        n_appended = 0
-        n_accepted = 0
-        for slot in active:
-            req = self._slot_req[slot]
-            n_emit = int(n_acc_h[slot]) + 1
-            n_accepted += n_emit - 1
-            req.spec_passes += 1
-            req.spec_accepted += n_emit - 1
-            new_toks = []
-            for t in range(n_emit):
-                tok = int(emit_h[t, slot])
-                self._append_token(req, tok, lp_h[t, slot],
-                                   tv_h[t, slot], ti_h[t, slot])
-                new_toks.append(tok)
-                n_appended += 1
-                self._next_tok[slot, 0] = tok
-                if self._hit_stop(req):
+        with self._span("engine.spec_pass") as span:
+            leases: list[int] = []
+            try:
+                for _ in active:
+                    sc = self.pool.lease_scratch()
+                    assert sc is not None        # n_scratch == n_slots
+                    leases.append(sc)
+                # branch_tags deliberately None: the draft scratch slot must
+                # continue the request's EXACT key schedule (fork copies the
+                # key verbatim) or spec decode loses its faithfulness
+                # contract — only best-of-n forks tag
+                self.pool.fork(active, leases)   # state + sampling params
+                total = self.pool.n_total
+                toks = np.zeros((total, 1), np.int32)
+                toks[leases, 0] = self._next_tok[active, 0]
+                scratch_mask = np.zeros((total,), bool)
+                scratch_mask[leases] = True
+                base = self._base_steps(active)
+                base[leases] = base[active]      # draft keys mirror live
+                limit = np.full((total,), k_eff, np.int32)
+                for s in active:
+                    limit[s] = min(depths[s], k_eff)
+                sp = self.pool.params.device()
+                cache, d_toks, d_logits = spec.propose(
+                    self.pool.cache, jnp.asarray(toks),
+                    jnp.asarray(scratch_mask), sp, jnp.asarray(base), k_eff)
+                # proposals were drafted at scratch rows; the verify wants
+                # them at their live slots' rows
+                perm = np.arange(total)
+                perm[active] = leases
+                perm = jnp.asarray(perm)
+                emit, n_acc, _, snap, v_lp, v_tv, v_ti = spec.verify(
+                    self.params, cache, jnp.asarray(self._next_tok),
+                    d_toks[:, perm], d_logits[:, perm],
+                    jnp.asarray(self.pool.active_mask()), sp,
+                    jnp.asarray(base), jnp.asarray(limit))
+                # the rollback: every live slot's row of ``snap`` is the
+                # state after exactly its accepted prefix
+                self.pool.cache = snap
+                emit_h, n_acc_h = np.asarray(emit), np.asarray(n_acc)
+                lp_h, tv_h, ti_h = (np.asarray(v_lp), np.asarray(v_tv),
+                                    np.asarray(v_ti))
+            finally:
+                for sc in leases:
+                    self.pool.release_scratch(sc)
+            n_appended = 0
+            n_accepted = 0
+            for slot in active:
+                req = self._slot_req[slot]
+                n_emit = int(n_acc_h[slot]) + 1
+                n_accepted += n_emit - 1
+                req.spec_passes += 1
+                req.spec_accepted += n_emit - 1
+                new_toks = []
+                for t in range(n_emit):
+                    tok = int(emit_h[t, slot])
+                    self._append_token(req, tok, lp_h[t, slot],
+                                       tv_h[t, slot], ti_h[t, slot])
+                    new_toks.append(tok)
+                    n_appended += 1
+                    self._next_tok[slot, 0] = tok
+                    if self._hit_stop(req):
+                        self._finish(slot)
+                        break                 # trim overshoot past stop/budget
+                self._deliver(req, new_toks)
+                if req.cancelled and not req.finished:
                     self._finish(slot)
-                    break                 # trim overshoot past stop/budget
-            self._deliver(req, new_toks)
-            if req.cancelled and not req.finished:
-                self._finish(slot)
         self.stats.record_decode(n_active=len(active),
                                  n_slots=self.ecfg.n_slots,
-                                 dt=self._now() - t0,
+                                 dt=span.t1 - span.t0,
                                  n_steps=k_eff + 1, n_tokens=n_appended)
         self.stats.record_spec(n_active=len(active),
                                n_drafted=k_eff * len(active),
@@ -1236,37 +1277,40 @@ class Engine:
         to do.  Admission peeks before popping: a best-of-n request
         needs ``n`` free slots at once, and blocks the line until it
         has them (admitting lower-priority work past it would starve
-        it forever under load)."""
-        did = self._sweep_cancelled()
-        while self._ready and self.pool.n_free:
-            req = self._ready[0][2]
-            if req.cancelled:
+        it forever under load).  All of it is the ``engine.step``
+        span."""
+        with self._span("engine.step"):
+            did = self._sweep_cancelled()
+            while self._ready and self.pool.n_free:
+                req = self._ready[0][2]
+                if req.cancelled:
+                    heapq.heappop(self._ready)
+                    self._drop_cancelled(req)
+                    continue
+                if req.params.n > self.pool.n_free:
+                    break
                 heapq.heappop(self._ready)
-                self._drop_cancelled(req)
-                continue
-            if req.params.n > self.pool.n_free:
-                break
-            heapq.heappop(self._ready)
-            if req.params.n > 1:
-                self._admit_group(req)
-            else:
-                self._admit(req)
-            did = True
-        if self.pool.n_active:
-            if self._spec is not None:
-                self._spec_pass()
-            else:
-                self._decode_burst()
-            did = True
-        if self._prefix is not None:
-            # the burst just host-synced: drain one deferred host-store
-            # snapshot offload (the cache-snapshot deadline) and adopt
-            # the cache's storage counters
-            if self._prefix.has_pending():
-                self._prefix.flush_pending(limit=1)
+                if req.params.n > 1:
+                    self._admit_group(req)
+                else:
+                    self._admit(req)
                 did = True
-            self.stats.sync_prefix(self._prefix.counters())
-        return did
+            if self.pool.n_active:
+                if self._spec is not None:
+                    self._spec_pass()
+                else:
+                    self._decode_burst()
+                did = True
+            if self._prefix is not None:
+                # the burst just host-synced: drain one deferred
+                # host-store snapshot offload (the cache-snapshot
+                # deadline) and adopt the cache's storage counters
+                if self._prefix.has_pending():
+                    with self._span("engine.flush_pending"):
+                        self._prefix.flush_pending(limit=1)
+                    did = True
+                self.stats.sync_prefix(self._prefix.counters())
+            return did
 
     # ------------------------------------------------------------------
     # Drive loop
@@ -1289,7 +1333,7 @@ class Engine:
                     continue
                 # TTFT/latency are measured from the (simulated) arrival,
                 # not from when the trace was queued before run()
-                req.t_submit = self._now()
+                req.t_submit = req.t_arrival = self._now()
                 self._push_ready(req)
             if not self.step() and self._pending:
                 wait = self._pending[0].arrival - (self._now() - t0)
@@ -1297,9 +1341,8 @@ class Engine:
                     time.sleep(min(wait, 0.05))
         if self._prefix is not None:
             # idle: no burst deadline competes with the offloads
-            self._prefix.flush_pending(limit=None)
+            with self._span("engine.flush_pending"):
+                self._prefix.flush_pending(limit=None)
             self.stats.sync_prefix(self._prefix.counters())
         self.stats.stop()
-        if self.logger:
-            self.logger.log(event="summary", **self.stats.summary())
         return self._finished

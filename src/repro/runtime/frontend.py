@@ -191,9 +191,12 @@ class AsyncFrontend:
         (so ``handle.shed`` is meaningful on return).  ``kw`` passes
         through to ``SLOScheduler.submit`` (tenant, slo, session,
         max_new, ...) or — without a scheduler — to ``Engine.submit``.
+        The request's ``t_arrival`` is stamped here, so its TTFT counts
+        the time it spent in the inbox and the scheduler's queue.
         """
         if self._task is None:
             raise RuntimeError("frontend not started")
+        kw.setdefault("t_arrival", self.engine._now())
         handle = StreamHandle(self._loop)
         self._handles.append(handle)
         submitted = asyncio.Event()

@@ -38,9 +38,10 @@ keep their slots and their pace *before* anything is refused:
      accounting in ``finalize`` counts that, decisions never read it).
 
 Determinism: every decision above is a function of (submission order,
-token counts, config) only.  Wall-clock appears exactly once — in
-``finalize``'s per-tenant violation accounting, which feeds dashboards
-and uses the engine's injectable clock, so tests pin it.
+token counts, config) only.  Wall-clock appears twice, on the
+engine's injectable clock, so tests pin it: ``submit`` stamps each
+request's arrival, and ``finalize``'s per-tenant violation accounting,
+which feeds dashboards, counts TTFT from that stamp.
 """
 from __future__ import annotations
 
@@ -192,13 +193,18 @@ class SLOScheduler:
 
     def submit(self, prompt, params: Optional[SamplingParams] = None,
                tenant: str = "default", slo: Optional[str] = None,
-               session: bool = False, **engine_kw) -> Ticket:
+               session: bool = False, t_arrival: Optional[float] = None,
+               **engine_kw) -> Ticket:
         """Admission-control a request and queue it for WFQ release.
 
         Runs the degradation ladder against the current projected wait
         (see module docstring); a shed ticket never reaches the engine.
-        ``engine_kw`` passes through to ``Engine.submit`` (max_new,
-        eos_id, stream_cb, ...)."""
+        ``t_arrival`` (on the engine's clock; None = now) becomes the
+        request's ``Request.t_arrival``, so its TTFT counts the time it
+        waited in this scheduler's queue.  ``engine_kw`` passes through
+        to ``Engine.submit`` (max_new, eos_id, stream_cb, ...)."""
+        if t_arrival is None:
+            t_arrival = self.engine._now()
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         params = (params if params is not None
                   else self.engine.ecfg.default_params)
@@ -232,7 +238,8 @@ class SLOScheduler:
         self._finish[tenant] = start + cost / self._weight(tenant)
         t = Ticket(tenant=tenant, slo=cls, cost=cost, start=start,
                    seq=self._seq, degraded=degraded,
-                   _kw=dict(engine_kw, params=params, session=session))
+                   _kw=dict(engine_kw, params=params, session=session,
+                            t_arrival=t_arrival))
         t._kw["prompt"] = prompt
         self._seq += 1
         self._queues.setdefault(tenant, collections.deque()).append(t)
@@ -328,17 +335,17 @@ class SLOScheduler:
         return eng._finished
 
     def finalize(self, finished: list) -> None:
-        """Wall-clock SLO violation accounting (the only place the
-        scheduler touches time, via the engine's injectable clock).
-        Cancelled requests are excluded — a client that hung up cannot
-        violate an SLO it stopped caring about."""
+        """Wall-clock SLO violation accounting, TTFT from the arrival
+        ``submit`` stamped (the engine's injectable clock).  Cancelled
+        requests are excluded — a client that hung up cannot violate an
+        SLO it stopped caring about."""
         by_req = {id(t.req): t for t in self.tickets if t.req is not None}
         for req in finished:
             t = by_req.get(id(req))
             if t is None or req.cancelled or req.t_first is None:
                 continue
             cls = t.slo
-            ttft = req.t_first - req.t_submit
+            ttft = req.t_first - req.t_arrival
             if cls.ttft_slo_s is not None and ttft > cls.ttft_slo_s:
                 self.engine.stats.record_slo_violation("ttft", t.tenant)
             if (cls.tpot_slo_s is not None and len(req.tokens) > 1

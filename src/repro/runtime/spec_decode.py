@@ -248,7 +248,7 @@ def _jit_draft_step(cfg, dcfg, n_layers: int, shard=None):
     full = n_layers == cfg.n_layers and dcfg == cfg
     cax = registry.cache_axes(cfg) if shard is not None else None
 
-    def _fn(pd, cache, toks, scratch_mask, sp, step):
+    def draft_step(pd, cache, toks, scratch_mask, sp, step):
         sampling.TRACE_COUNTS["draft_step"] += 1
         with sharding.shard_ctx(shard):
             cd = (cache if full
@@ -264,7 +264,7 @@ def _jit_draft_step(cfg, dcfg, n_layers: int, shard=None):
                 new_cache = sharding.constrain_tree(new_cache, cax)
             tok = sampling.sample(logits[:, -1, :], sp, step)
         return tok[:, None], logits[:, -1, :], new_cache
-    return jax.jit(_fn)
+    return jax.jit(draft_step)
 
 
 @functools.lru_cache(maxsize=None)
@@ -277,8 +277,8 @@ def _jit_verify(cfg, k: int, shard=None):
     traced arrays."""
     cax = registry.cache_axes(cfg) if shard is not None else None
 
-    def _fn(p, cache, x0, draft_toks, draft_logits, active, sp, step,
-            depth_limit):
+    def verify(p, cache, x0, draft_toks, draft_logits, active, sp, step,
+               depth_limit):
         sampling.TRACE_COUNTS["verify"] += 1
         with sharding.shard_ctx(shard):
             # x0 (total, 1) pending tokens; draft_toks (k, total)
@@ -300,7 +300,7 @@ def _jit_verify(cfg, k: int, shard=None):
             # streams stay bitwise identical to the surface-free verify
             lp, tv, ti = jax.vmap(sampling.token_logprobs)(tl, emit)
         return emit, n_acc, pending, snap, lp, tv, ti
-    return jax.jit(_fn)
+    return jax.jit(verify)
 
 
 class SpecDecoder:

@@ -41,39 +41,39 @@ from repro.runtime import sampling
 def _jit_gather(cfg, shard=None):
     cax = registry.cache_axes(cfg) if shard is not None else None
 
-    def _fn(c, i):
+    def pool_gather(c, i):
         with sharding.shard_ctx(shard):
             out = registry.gather_slots(cfg, c, i)
             if shard is not None:
                 out = sharding.constrain_tree(out, cax)
         return out
-    return jax.jit(_fn)
+    return jax.jit(pool_gather)
 
 
 @functools.lru_cache(maxsize=None)
 def _jit_scatter(cfg, shard=None):
     cax = registry.cache_axes(cfg) if shard is not None else None
 
-    def _fn(c, s, i):
+    def pool_scatter(c, s, i):
         with sharding.shard_ctx(shard):
             out = registry.scatter_slots(cfg, c, s, i)
             if shard is not None:
                 out = sharding.constrain_tree(out, cax)
         return out
-    return jax.jit(_fn)
+    return jax.jit(pool_scatter)
 
 
 @functools.lru_cache(maxsize=None)
 def _jit_mask(cfg, shard=None):
     cax = registry.cache_axes(cfg) if shard is not None else None
 
-    def _fn(o, n, m):
+    def pool_mask(o, n, m):
         with sharding.shard_ctx(shard):
             out = registry.mask_slots(cfg, o, n, m)
             if shard is not None:
                 out = sharding.constrain_tree(out, cax)
         return out
-    return jax.jit(_fn)
+    return jax.jit(pool_mask)
 
 
 @functools.lru_cache(maxsize=None)
@@ -83,14 +83,14 @@ def _jit_fork(cfg, shard=None):
     the same op, so a fork can never tear payload from scale."""
     cax = registry.cache_axes(cfg) if shard is not None else None
 
-    def _fn(c, src, dst):
+    def pool_fork(c, src, dst):
         with sharding.shard_ctx(shard):
             out = registry.scatter_slots(
                 cfg, c, registry.gather_slots(cfg, c, src), dst)
             if shard is not None:
                 out = sharding.constrain_tree(out, cax)
         return out
-    return jax.jit(_fn)
+    return jax.jit(pool_fork)
 
 
 class SlotStatePool:
