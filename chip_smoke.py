@@ -170,9 +170,12 @@ def compare_logits(a, b, reqs):
     return diff, scale, agree, total
 
 
-def check_reference(engine: Engine, greedy, n_decode: int = N_DECODE):
+def check_reference(engine: Engine, params, greedy,
+                    n_decode: int = N_DECODE):
     """Replay ``greedy`` requests through the served decode path and the
-    XLA f32 reference.  Returns a dict of the comparison."""
+    XLA f32 reference on ``params``, the f32 weights the engine was
+    built from (the engine's own tree may hold its projections at the
+    compute dtype).  Returns a dict of the comparison."""
     n_slots, max_seq = engine.ecfg.n_slots, engine.ecfg.max_seq
     served, hlo = replay_logits(engine.cfg, engine.params,
                                 engine.prefill_params, greedy, n_slots,
@@ -181,9 +184,8 @@ def check_reference(engine: Engine, greedy, n_decode: int = N_DECODE):
                                   dtype="float32", state_dtype="f32",
                                   weight_dtype="f32")
     with jax.default_matmul_precision("highest"):
-        ref, _ = replay_logits(ref_cfg,
-                               engine.prefill_params, engine.prefill_params,
-                               greedy, n_slots, max_seq, n_decode)
+        ref, _ = replay_logits(ref_cfg, params, params, greedy, n_slots,
+                               max_seq, n_decode)
     diff, scale, agree, total = compare_logits(served, ref, greedy)
     return {"max_logit_diff": diff, "ref_logit_scale": scale,
             "agree": agree, "total": total,
@@ -231,7 +233,7 @@ def one_chip(args) -> None:
     check_budgets(reqs)
     log(f"run seconds (8 requests, set-up information, not a device "
         f"metric): {run_s:.2f}; {sum(len(r.tokens) for r in reqs)} tokens")
-    res = check_reference(engine, greedy_pair(reqs))
+    res = check_reference(engine, params, greedy_pair(reqs))
     if path == "xla":
         log("decode path is the XLA step: no Pallas kernel to check")
     else:

@@ -308,7 +308,9 @@ def embed_init(cfg, key):
 
 
 def embed_apply(cfg, p, tokens, dtype):
-    return p["tok"].astype(dtype)[tokens]
+    # gather, then cast: the same values without converting the whole
+    # table (which stays wide: the tied head reads it at f32)
+    return p["tok"][tokens].astype(dtype)
 
 
 def unembed_init(cfg, key):

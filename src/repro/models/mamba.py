@@ -19,6 +19,11 @@ from repro.kernels import ops
 from repro.models import blocks
 from repro.parallel.sharding import Param, constrain
 
+#: the mixer's matrices: every consumer hands them to ``blocks.dense`` at
+#: the compute dtype (cfg.dtype), so a copy held at that dtype feeds
+#: bitwise the same matmul operands (``registry.precast_params``)
+PROJECTIONS = ("in_proj", "x_proj", "dt_proj", "out_proj")
+
 
 def _a_and_scale(p):
     """The SSM A matrix as the step math consumes it: (A, a_scale).

@@ -19,7 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.models import jamba, mamba_lm, transformer, xlstm
+from repro.models import jamba, mamba, mamba_lm, transformer, xlstm
 
 
 _FAMILIES = {
@@ -63,6 +63,45 @@ def quantize_params(cfg, values):
     if not weight_quant.is_quantized(cfg.weight_dtype):
         return values
     return weight_quant.quantize_tree(values)
+
+
+def precast_params(cfg, values):
+    """The serving copy of a PLAIN-VALUE param tree: the ``w`` of every
+    Mamba mixer projection (``mamba.PROJECTIONS``) held at the compute
+    dtype (cfg.dtype) where it is stored as a wider float and the
+    weights are not quantized.  Each consumer casts it to that dtype
+    before its matmul anyway, so the copy gives bitwise the same
+    operands and the steps no longer cast it on every call.  The
+    embedding (the tied head computes logits at f32), an untied head,
+    norms, conv, A, D, dt_bias, int8 codes and scales, and attention,
+    MoE and xLSTM leaves stay as they are.  One jitted cast, waited
+    for; every other leaf aliases ``values``, which is neither changed
+    nor donated.  Returns (tree, bytes cast)."""
+    from repro.core import weight_quant
+    if weight_quant.is_quantized(cfg.weight_dtype):
+        return values, 0
+    dt = jnp.dtype(cfg.dtype)
+    flat, treedef = jax.tree_util.tree_flatten_with_path(values)
+
+    def wanted(path, leaf):
+        keys = tuple(getattr(k, "key", None) for k in path[-2:])
+        return (len(keys) == 2 and keys[1] == "w"
+                and keys[0] in mamba.PROJECTIONS
+                and jnp.issubdtype(leaf.dtype, jnp.floating)
+                and leaf.dtype.itemsize > dt.itemsize)
+
+    at = [i for i, (path, leaf) in enumerate(flat) if wanted(path, leaf)]
+    if not at:
+        return values, 0
+    ins = tuple(flat[i][1] for i in at)
+    # an element-wise cast: each output keeps its input's sharding
+    outs = jax.block_until_ready(
+        jax.jit(lambda ws: tuple(w.astype(dt) for w in ws))(ins))
+    leaves = [leaf for _, leaf in flat]
+    for i, w in zip(at, outs):
+        leaves[i] = w
+    return (jax.tree_util.tree_unflatten(treedef, leaves),
+            sum(w.nbytes for w in outs))
 
 
 def init_cache(cfg, batch, max_seq, dtype=None):

@@ -303,10 +303,15 @@ class EngineConfig:
     # runs once per request, so it keeps serving from the caller's
     # f32 master weights (``Engine.prefill_params`` aliases them —
     # no copy) while every per-token decode/verify step streams the
-    # int8 tree.  Composes with state_dtype/kv_cache_dtype (W8A8 +
-    # quantized state/KV) and with ``mesh`` (scale leaves shard with
-    # their payloads).  None = keep the model config's setting — the
-    # default leaves engines byte-identical to unquantized serving.
+    # int8 tree.  Unquantized engines serve prefill and decode from
+    # one tree (``Engine.params``, which ``prefill_params`` aliases):
+    # the caller's, with the Mamba projections held at the compute
+    # dtype when that is narrower (``registry.precast_params``;
+    # ``Engine.precast_bytes`` counts them).  Composes with
+    # state_dtype/kv_cache_dtype (W8A8 + quantized state/KV) and with
+    # ``mesh`` (scale leaves shard with their payloads).  None = keep
+    # the model config's setting — the default leaves engines
+    # byte-identical to unquantized serving.
     weight_dtype: Optional[str] = None
     # speculative decoding: None = plain decode bursts; a DraftConfig
     # turns every decode step into a fork -> K-draft -> batched-verify
@@ -470,6 +475,14 @@ class Engine:
                         rules))
             else:
                 prefill_params = params
+        # the projection matrices at the compute dtype, cast once here
+        # and not by every step; placed tree in, placed tree out
+        with tracing.span("engine.precast", clock=clock):
+            precast, self.precast_bytes = registry.precast_params(cfg,
+                                                                  params)
+        if prefill_params is params:
+            prefill_params = precast
+        params = precast
         self.cfg = cfg
         self.params = params
         self.prefill_params = prefill_params

@@ -47,7 +47,7 @@ def test_serving_and_reference_phases(dtype, step_impl):
     cs.check_budgets(reqs)
     pair = cs.greedy_pair(reqs)
     assert sorted(r.prompt.size for r in pair) == list(LENS)
-    res = cs.check_reference(engine, pair)
+    res = cs.check_reference(engine, params, pair)
     assert res["max_logit_diff"] <= cs.LOGIT_RTOL * res["ref_logit_scale"]
     assert res["agree"] == res["total"] == 2 * (cs.N_DECODE + 1)
     if dtype == "float32":

@@ -17,6 +17,7 @@ making the described chip the default device.
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +28,7 @@ from repro import configs
 from repro.core import state_quant
 from repro.kernels import backend
 from repro.kernels import decode_step as dsk
-from repro.models import mamba_lm, registry
+from repro.models import mamba, mamba_lm, registry
 from repro.parallel import sharding
 
 SLOTS = 8
@@ -129,3 +130,58 @@ def test_auto_decode_step_compiles(chip, arch, state_dtype, weight_dtype,
                                                         {"tokens": t}),
                    p, cache, toks)
     assert "tpu_custom_call" in hlo
+
+
+def _ops(hlo, op, dtype):
+    """Result shapes of the ``op`` instructions of ``dtype`` in a
+    compiled HLO text."""
+    pat = rf"= {dtype}\[([\d,]*)\]\S* {op}\("
+    return {tuple(int(x) for x in m.group(1).split(",") if x)
+            for m in re.finditer(pat, hlo)}
+
+
+@pytest.mark.parametrize("arch,slots,path", [
+    ("mamba-1.4b", 64, "fused"),
+    ("mamba-130m", 8, "megakernel"),
+])
+def test_precast_decode_step_compiles(chip, arch, slots, path):
+    """The decode step over the engine's precast tree (bf16 projection
+    stacks, ``registry.precast_params``) compiles at published widths;
+    "auto" still picks the same path with the VMEM need read from the
+    bf16 leaves; the program converts no weight stack or embedding
+    table, and copies no weight stack that the f32 tree's step did not
+    copy already."""
+    cfg = configs.get_config(arch)
+    where = SingleDeviceSharding(chip)
+    f32 = sharding.tree_values(registry.abstract_params(cfg))
+    cast = jax.eval_shape(lambda v: registry.precast_params(cfg, v)[0], f32)
+    mixer = cast["layers"]["mixer"]
+    weights = {tuple(cast["embed"]["tok"].shape)}
+    for name in mamba.PROJECTIONS:
+        assert mixer[name]["w"].dtype == jnp.bfloat16
+        weights.add(tuple(mixer[name]["w"].shape))
+        weights.add(tuple(mixer[name]["w"].shape[1:]))
+    cache = _sds(sharding.tree_values(
+        registry.abstract_cache(cfg, slots, 256)), where)
+    toks = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=where)
+
+    def step(p, c, t):
+        return registry.decode_step(cfg, p, c, {"tokens": t})
+
+    hlo = {}
+    with on(chip):
+        for name, tree in (("f32", f32), ("bf16", cast)):
+            p = _sds(tree, where)
+            assert mamba_lm.decode_path(cfg, p, cache) == path
+            hlo[name] = _hlo(step, p, cache, toks)
+    assert "tpu_custom_call" in hlo["bf16"]
+    if path == "fused":
+        # the control: the f32 tree's step casts its stacks in XLA (the
+        # megakernel casts its f32 blocks in-kernel, out of HLO's sight)
+        assert _ops(hlo["f32"], "convert", "bf16") & weights
+    assert not _ops(hlo["bf16"], "convert", "bf16") & weights
+    copied = {name: set.union(*(_ops(hlo[name], op, dt) & weights
+                                for op in ("copy", "copy-done")
+                                for dt in ("f32", "bf16")))
+              for name in hlo}
+    assert copied["bf16"] <= copied["f32"]
