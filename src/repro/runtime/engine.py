@@ -247,7 +247,9 @@ def _jit_decode_sample(cfg, shard=None):
             tok = sampling.sample(last, sp, step)
             lp, tv, ti = sampling.token_logprobs(last, tok)
         return tok[:, None], lp, tv, ti, new_cache
-    return jax.jit(_decode_fn)
+    # the pool cache is donated: the steps of a burst chain through one
+    # pool buffer instead of each enqueued step allocating its own
+    return jax.jit(_decode_fn, donate_argnums=1)
 
 
 def derive_seed(engine_seed: int, req_id: int) -> int:

@@ -534,3 +534,35 @@ def test_raising_stream_cb_isolated_and_auto_cancelled():
     assert [t for batch in a_deliveries for t in batch] == a.tokens
     assert eng.pool.n_active == 0 and eng.pool.n_free == eng.pool.n_slots
     assert eng.stats.n_cancelled == 1
+
+
+@pytest.mark.parametrize("name", ["mamba-130m", "jamba-v0.1-52b",
+                                  "xlstm-350m"])
+def test_decode_burst_reuses_the_pool_buffer(name):
+    """A decode burst donates the pool cache to its first step and
+    chains the rest through the steps' outputs: the cache a burst
+    started from is released, the engine's new pool is live, and the
+    tokens are those of the same engine with an undonating step."""
+    cfg, params = _setup(name)
+    prompts = [np.arange(1, 6, dtype=np.int32),
+               np.arange(7, 15, dtype=np.int32)]
+
+    def serve(donate):
+        eng = Engine(cfg, params, EngineConfig(n_slots=2, max_seq=40,
+                                               sched_quantum=4))
+        if not donate:
+            eng._decode = jax.jit(eng._decode.__wrapped__)
+        # a stop id caps each burst at the quantum
+        reqs = [eng.submit(p, max_new=12, eos_id=cfg.vocab - 1)
+                for p in prompts]
+        eng.step()                       # both admissions, one burst
+        before = eng.pool.cache
+        eng.step()                       # the next burst starts there
+        assert all(leaf.is_deleted() for leaf in
+                   jax.tree.leaves(before)) == donate
+        assert not any(leaf.is_deleted()
+                       for leaf in jax.tree.leaves(eng.pool.cache))
+        eng.run()
+        return [r.tokens for r in reqs]
+
+    assert serve(True) == serve(False)
